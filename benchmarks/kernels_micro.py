@@ -1,9 +1,10 @@
 """Kernel microbenchmarks.
 
-This container is CPU-only: Pallas kernels execute in interpret mode, so
-absolute times are NOT TPU performance — these rows exist to (a) prove the
-kernels execute and match their oracles at benchmark shapes and (b) time the
-portable XLA fallback paths that the CPU examples actually use."""
+The kernels here are called with their default ``interpret=True``, so
+they execute in interpret mode on any backend and the absolute times are
+NOT TPU performance — these rows exist to (a) prove the kernels execute
+and match their oracles at benchmark shapes and (b) time the portable XLA
+paths that the CPU examples actually use."""
 from __future__ import annotations
 
 import time

@@ -1,21 +1,15 @@
 """Pallas TPU kernels (+ jit wrappers in ops.py, oracles in ref.py).
 
-``enabled()`` gates the model-layer fast path: set REPRO_PALLAS=1 (or call
-``enable(True)``) to route attention/SSD through the Pallas kernels — native
-on TPU, interpret mode elsewhere. The portable XLA implementations remain
-the default (and the dry-run path).
+The model layers pick an implementation by platform: on a TPU backend
+``enabled()`` is true and the served attention sites (paged decode,
+chunked prefill, flash prefill) call the native Pallas kernels; on every
+other backend they run the pure-jnp oracles in ``ref.py`` and the XLA
+paths in ``repro.models``. Tests that want the Pallas path on the CPU
+monkeypatch ``enabled`` — ``ops`` then runs the kernels in interpret mode.
 """
-import os
-
-_FORCED = None
-
-
-def enable(flag: bool):
-    global _FORCED
-    _FORCED = bool(flag)
+import jax
 
 
 def enabled() -> bool:
-    if _FORCED is not None:
-        return _FORCED
-    return os.environ.get("REPRO_PALLAS", "0") == "1"
+    """True where the Pallas kernels compile natively (a TPU backend)."""
+    return jax.default_backend() == "tpu"

@@ -1,11 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
 On TPU the kernels compile natively (interpret=False); everywhere else they
-run in interpret mode (kernel body executed in Python/XLA on CPU) so the
-same call sites validate on this container. ``repro.models`` uses the
-portable XLA implementations by default; these ops are the TPU-target fast
-path, selected via ``use_pallas=True`` at the model level or called
-directly.
+run in interpret mode (kernel body executed in Python/XLA on the CPU), so
+the same call sites validate without a chip. ``repro.models`` calls these
+ops where ``repro.kernels.enabled()`` holds (a TPU backend) and the
+portable XLA implementations elsewhere.
 """
 from __future__ import annotations
 
@@ -49,19 +48,21 @@ def decode_attention(q, k, v, valid_len, scale=None, block_k=512):
 
 @partial(jax.jit, static_argnames=("scale",))
 def paged_decode_attention(q, k_pages, v_pages, block_table, valid_lens,
-                           scale=None):
+                           scale=None, layer=None):
     """q (B,H,D) one token; k_pages/v_pages (P,page_size,Hkv,D) shared
-    pool; block_table (B,N); valid_lens (B,)."""
+    pool, or (L,P,page_size,Hkv,D) stacked pools read at ``layer``;
+    block_table (B,N); valid_lens (B,)."""
     return _paged.paged_decode_attention(q, k_pages, v_pages, block_table,
-                                         valid_lens, scale,
+                                         valid_lens, scale, layer=layer,
                                          interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("scale", "block_q"))
 def chunked_prefill_attention(q, k_pages, v_pages, block_table, start_pos,
-                              scale=None, block_q=None):
+                              scale=None, block_q=None, layer=None):
     """q (B,T,H,D) one page-aligned prefill chunk per sequence;
-    k_pages/v_pages (P,page_size,Hkv,D) shared pool already holding the
+    k_pages/v_pages (P,page_size,Hkv,D) shared pool (or stacked
+    (L,P,page_size,Hkv,D) pools read at ``layer``) already holding the
     chunk's K/V; block_table (B,N); start_pos (B,) absolute chunk starts.
 
     Rows are independent — the fused tick batches chunk runs from
@@ -70,6 +71,7 @@ def chunked_prefill_attention(q, k_pages, v_pages, block_table, start_pos,
     contribute exact zeros, so bucketing never perturbs real rows."""
     return _chunk.chunked_prefill_attention(q, k_pages, v_pages, block_table,
                                             start_pos, scale, block_q=block_q,
+                                            layer=layer,
                                             interpret=_interpret())
 
 

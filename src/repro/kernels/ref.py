@@ -43,10 +43,13 @@ def decode_attention(q, k, v, valid_len, scale):
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, valid_lens,
-                           scale):
-    """q (B, H, D); k_pages/v_pages (P, page_size, Hkv, D); block_table
-    (B, N) int32; valid_lens (B,) int32. Gathers each sequence's K/V
-    through its block table, masks positions >= valid_lens[b]."""
+                           scale, layer=None):
+    """q (B, H, D); k_pages/v_pages (P, page_size, Hkv, D), or
+    (L, P, page_size, Hkv, D) read at ``layer``; block_table (B, N) int32;
+    valid_lens (B,) int32. Gathers each sequence's K/V through its block
+    table, masks positions >= valid_lens[b]."""
+    if k_pages.ndim == 5:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     B, H, D = q.shape
     P, ps, Hkv, _ = k_pages.shape
     N = block_table.shape[1]
@@ -65,11 +68,14 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, valid_lens,
 
 
 def chunked_prefill_attention(q, k_pages, v_pages, block_table, start_pos,
-                              scale):
+                              scale, layer=None):
     """q (B, T, H, D) — T fresh tokens, token t of sequence b at absolute
     position ``start_pos[b] + t``; k_pages/v_pages (P, page_size, Hkv, D)
-    already holding the chunk's own K/V; block_table (B, N) int32;
-    start_pos (B,) int32. Causal over absolute positions."""
+    (or (L, P, page_size, Hkv, D) read at ``layer``) already holding the
+    chunk's own K/V; block_table (B, N) int32; start_pos (B,) int32.
+    Causal over absolute positions."""
+    if k_pages.ndim == 5:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     B, T, H, D = q.shape
     P, ps, Hkv, _ = k_pages.shape
     N = block_table.shape[1]

@@ -1,14 +1,18 @@
 """Serving driver: ``python -m repro.launch.serve --requests 50``.
 
 Boots a three-tier island mesh (personal laptop+phone, private edge, public
-cloud), serves a real reduced model on the laptop SHORE island, routes a
-healthcare workload through WAVES and prints the per-island distribution,
-privacy accounting and latency percentiles.
+cloud), serves a real model on its SHORE islands, routes a healthcare
+workload through WAVES and prints the per-island distribution, privacy
+accounting and latency percentiles.
 
-``--batched`` swaps the per-request Algorithm-1 loop for the tick-based
-batched orchestrator: the whole pending pool is routed per scheduling tick
-through the capacity-aware ``route_batch_tick`` kernel and SHORE work runs
-through per-island continuous batchers.
+The model is ``--arch`` at its published widths, with random bfloat16
+weights from ``--seed`` initialised once and shared by every island on a
+device; ``--reduced`` serves the 2-layer smoke variant instead (the CPU
+recipe). ``--batched`` swaps the per-request Algorithm-1 loop for the
+tick-based batched orchestrator: the whole pending pool is routed per
+scheduling tick through the capacity-aware ``route_batch_tick`` kernel and
+SHORE work runs through per-island continuous batchers, island ``i`` on
+device ``i % n`` (``serving.engine.build_island_batchers``).
 
 ``--trace out.json`` (implies ``--batched``) attaches the operator-side
 span tracer (``repro.obs``) to the run and writes the request-span
@@ -20,6 +24,10 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+from pathlib import Path
+
+import jax
 
 from repro.configs.base import ARCH_IDS, get_config
 from repro.core.islands import (IslandRegistry, cloud_island, edge_island,
@@ -29,7 +37,33 @@ from repro.core.mist import MIST
 from repro.core.tide import TIDE
 from repro.core.waves import WAVES, Policy
 from repro.core.workload import healthcare_workload
-from repro.serving.engine import InferenceEngine, LocalModelServer
+from repro.models.model import get_model
+from repro.serving.engine import (InferenceEngine, LocalModelServer,
+                                  TickOrchestrator, build_island_batchers)
+
+# Context per request (prompt + generated). With one decode slot per unit
+# of island capacity, the default mesh's four SHORE islands hold 12 slots
+# of 2048 tokens: qwen3-4b's K/V at 147,456 B per token fills 3.6 GB of
+# pages next to its 8.05 GB of bfloat16 weights, inside one 16 GB v5e
+# chip with room for activations — and no pool can stall.
+MAX_LEN = 2048
+SLOTS_PER_CAPACITY_UNIT = 1.0
+
+# Persistent compile cache used where JAX_COMPILATION_CACHE_DIR is unset:
+# a fixed directory of the checkout (git-ignored), so every run of the
+# same checkout finds what the last one compiled.
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def setup_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+    A set ``JAX_COMPILATION_CACHE_DIR`` is JAX's own setting and stands;
+    otherwise the cache goes to ``CACHE_DIR``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)
 
 
 def build_mesh(policy=None, buffer="moderate", classifier=None):
@@ -55,9 +89,29 @@ def build_mesh(policy=None, buffer="moderate", classifier=None):
     return reg, waves
 
 
+def build_orchestrator(cfg, registry, waves, *, params=None, seed=0,
+                       cache="auto", prefill="chunked", tracer=None):
+    """The batched serving stack over ``registry``'s SHORE islands: one
+    continuous batcher per island (``MAX_LEN`` context, one decode slot
+    per unit of capacity, pools that hold every slot), behind a
+    ``TickOrchestrator``. ``params`` default to ``cfg``'s dtype from
+    ``seed``."""
+    if params is None:
+        params = get_model(cfg).init(jax.random.PRNGKey(seed))
+    batchers = build_island_batchers(
+        cfg, registry, cache=cache, params=params, max_len=MAX_LEN,
+        slots_per_capacity_unit=SLOTS_PER_CAPACITY_UNIT, seed=seed,
+        prefill=prefill)
+    return TickOrchestrator(waves, registry, batchers, seed=seed,
+                            tracer=tracer)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m", choices=ARCH_IDS)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the 2-layer smoke variant of --arch (the "
+                         "CPU recipe) instead of its published widths")
     ap.add_argument("--requests", type=int, default=50)
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--buffer", default="moderate",
@@ -68,9 +122,6 @@ def main(argv=None):
     ap.add_argument("--batched", action="store_true",
                     help="tick-based batched orchestrator instead of the "
                          "per-request loop")
-    ap.add_argument("--slots", type=int, default=4,
-                    help="continuous-batching decode slots per SHORE island "
-                         "(--batched only)")
     ap.add_argument("--cache", default="auto",
                     choices=("auto", "stacked", "paged"),
                     help="KV-cache manager for --batched SHORE islands: "
@@ -101,28 +152,24 @@ def main(argv=None):
         print(f"MIST stage-2 classifier trained "
               f"(train acc {clf.train_accuracy:.3f})")
 
+    setup_compile_cache()
     reg, waves = build_mesh(Policy(mode=args.mode), args.buffer, clf)
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    params = get_model(cfg).init(jax.random.PRNGKey(args.seed))
     wl = healthcare_workload(args.requests, seed=args.seed)
     tracer = None
     if args.batched:
-        from repro.serving.batcher import make_batcher
-        from repro.serving.engine import TickOrchestrator
         if args.trace:
             from repro.obs import Tracer
             tracer = Tracer()
-        batchers = {iid: make_batcher(cfg, cache=args.cache,
-                                      num_slots=args.slots,
-                                      prefill=args.prefill,
-                                      max_len=128, seed=args.seed)
-                    for iid in ("laptop", "home-nas")}
-        eng = TickOrchestrator(waves, reg, batchers, seed=args.seed,
-                               tracer=tracer)
+        eng = build_orchestrator(cfg, reg, waves, params=params,
+                                 seed=args.seed, cache=args.cache,
+                                 prefill=args.prefill, tracer=tracer)
     else:
-        servers = {"laptop": LocalModelServer(cfg, max_len=128,
-                                              seed=args.seed),
-                   "home-nas": LocalModelServer(cfg, max_len=128,
-                                                seed=args.seed)}
+        servers = {iid: LocalModelServer(cfg, params=params, max_len=MAX_LEN)
+                   for iid in ("laptop", "home-nas")}
         eng = InferenceEngine(waves, reg, servers, seed=args.seed)
     for req, kind in wl:
         eng.submit(req, max_new_tokens=args.max_new_tokens)

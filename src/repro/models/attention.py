@@ -101,35 +101,48 @@ def attend_blocked(q, k, v, q_pos, k_pos, scale, window=None, prefix_len=0,
     return out.astype(q.dtype)
 
 
-def attend_decode_paged(q, k_pages, v_pages, block_table, valid_lens, scale):
+def attend_decode_paged(q, k_pages, v_pages, block_table, valid_lens, scale,
+                        layer=None):
     """One-token decode against a paged pool: q (B,1,H,D); pages
-    (P,page_size,Hkv,D); block_table (B,N); valid_lens (B,)."""
+    (P,page_size,Hkv,D), or every layer's stacked (L,P,page_size,Hkv,D)
+    read at ``layer``; block_table (B,N); valid_lens (B,). The Pallas
+    kernel on TPU, the gather oracle elsewhere."""
     from repro import kernels as _k
-    from repro.kernels import ref as _kref
-    B, _, H, D = q.shape
     if _k.enabled():
         from repro.kernels import ops as _kops
         o = _kops.paged_decode_attention(q[:, 0], k_pages, v_pages,
-                                         block_table, valid_lens, scale)
+                                         block_table, valid_lens, scale,
+                                         layer=layer)
     else:
+        from repro.kernels import ref as _kref
         o = _kref.paged_decode_attention(q[:, 0], k_pages, v_pages,
-                                         block_table, valid_lens, scale)
+                                         block_table, valid_lens, scale,
+                                         layer=layer)
     return o[:, None]
 
 
-def attend_chunk_paged(q, k_pages, v_pages, block_table, start_pos, scale):
+def attend_chunk_paged(q, k_pages, v_pages, block_table, start_pos, scale,
+                       layer=None):
     """Page-aligned prefill chunk against the paged pool: q (B,T,H,D) —
     T fresh tokens already scattered into the pool — attends causally over
     everything the block table covers; start_pos (B,) absolute position of
-    the chunk's first token."""
+    the chunk's first token; stacked pools are read at ``layer``. The
+    Pallas kernel on TPU, the gather oracle elsewhere."""
     from repro import kernels as _k
     if _k.enabled():
         from repro.kernels import ops as _kops
         return _kops.chunked_prefill_attention(q, k_pages, v_pages,
-                                               block_table, start_pos, scale)
+                                               block_table, start_pos, scale,
+                                               layer=layer)
     from repro.kernels import ref as _kref
     return _kref.chunked_prefill_attention(q, k_pages, v_pages, block_table,
-                                           start_pos, scale)
+                                           start_pos, scale, layer=layer)
+
+
+def _at_layer(layer, *idx):
+    """Scatter index into a paged pool: per-layer pools take ``idx``,
+    stacked (L, ...) pools are written at ``layer`` in place."""
+    return idx if layer is None else (layer, *idx)
 
 
 def attend_decode(q, k_cache, v_cache, valid_len, scale):
@@ -181,7 +194,7 @@ def attn_cache_spec(cfg, batch, max_len, window=None):
 
 def attn_apply(cfg, p, x, positions, *, mode, cache=None, window=None,
                use_blocked=True, triangular=True, block_table=None,
-               dst_page=None):
+               dst_page=None, layer=None):
     """mode 'full' (train/prefill), 'chunk' (paged chunked prefill: x is
     (1, T, d) with T == page_size, positions a (T,) vector of absolute
     positions) or 'decode' (x is (B,1,d), positions is a scalar absolute
@@ -194,7 +207,8 @@ def attn_apply(cfg, p, x, positions, *, mode, cache=None, window=None,
     through ``block_table`` (B, N). Chunk mode scatters the whole chunk's
     K/V onto ``dst_page`` (the reserved scratch page when the chunk is
     prefix-shared and its real page already holds identical K/V) before
-    gathering."""
+    gathering. With ``layer`` set, the paged leaves are every layer's
+    stacked pool and only that layer's pages are written and read."""
     B = x.shape[0]
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     scale = hd ** -0.5
@@ -269,29 +283,30 @@ def attn_apply(cfg, p, x, positions, *, mode, cache=None, window=None,
         # garbage that causal masking keeps out of every real row)
         C = S // ps
         dst = dst_page if dst_page.ndim == 2 else dst_page[None]  # (B,C)
-        new_kp = cache["k_pages"].at[dst.reshape(-1)].set(
+        at = _at_layer(layer, dst.reshape(-1))
+        new_kp = cache["k_pages"].at[at].set(
             kd.reshape(B * C, ps, *kd.shape[2:]))
-        new_vp = cache["v_pages"].at[dst.reshape(-1)].set(
+        new_vp = cache["v_pages"].at[at].set(
             vd.reshape(B * C, ps, *vd.shape[2:]))
         o = attend_chunk_paged(q, new_kp, new_vp, block_table, pos[:, 0],
-                               scale)
+                               scale, layer)
         new_cache = {"k_pages": new_kp, "v_pages": new_vp}
     elif "k_pages" in cache:  # decode against the paged pool
         pos = positions          # scalar or (B,) absolute positions
         posb = jnp.zeros((B,), jnp.int32) + pos
         q = apply_rope(q, posb[:, None], cfg.rope_theta)
         k = apply_rope(k, posb[:, None], cfg.rope_theta)
-        ps = cache["k_pages"].shape[1]
+        ps = cache["k_pages"].shape[-3]
         kd = k.astype(cache["k_pages"].dtype)
         vd = v.astype(cache["v_pages"].dtype)
         # scatter the new token into each sequence's current page; inactive
         # slots carry all-zero block tables, landing on the scratch page
         pi = block_table[jnp.arange(B), posb // ps]
-        off = posb % ps
-        new_kp = cache["k_pages"].at[pi, off].set(kd[:, 0])
-        new_vp = cache["v_pages"].at[pi, off].set(vd[:, 0])
+        at = _at_layer(layer, pi, posb % ps)
+        new_kp = cache["k_pages"].at[at].set(kd[:, 0])
+        new_vp = cache["v_pages"].at[at].set(vd[:, 0])
         o = attend_decode_paged(q, new_kp, new_vp, block_table, posb + 1,
-                                scale)
+                                scale, layer)
         new_cache = {"k_pages": new_kp, "v_pages": new_vp}
     else:  # decode
         from repro.models.decode_sharded import (seq_sharded_decode,
@@ -330,14 +345,7 @@ def attn_apply(cfg, p, x, positions, *, mode, cache=None, window=None,
                     * new_cache["v_scale"][..., None]
             else:
                 k_att, v_att = new_k, new_v
-            from repro import kernels as _k
-            if _k.enabled() and W % 128 == 0:
-                from repro.kernels import ops as _kops
-                o = _kops.decode_attention(
-                    q[:, 0], k_att, v_att, valid, scale,
-                    block_k=min(512, W))[:, None]
-            else:
-                o = attend_decode(q, k_att, v_att, valid, scale)
+            o = attend_decode(q, k_att, v_att, valid, scale)
 
     o = shard(o, "batch", None, "heads", None)
     y = jnp.einsum("bsq,qd->bsd", o.reshape(B, o.shape[1], H * hd), p["wo"])

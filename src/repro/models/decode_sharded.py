@@ -88,15 +88,8 @@ def seq_sharded_decode(k_cache, v_cache, k_new, v_new, q, pos, window,
     cache_spec = P(bspec, "model", None, None)
     new_spec = P(bspec, None, None, None)
     fn = partial(_inner, scale=scale, model_axis="model")
-    # jax.shard_map(check_vma=...) only exists on newer jax; fall back to
-    # the experimental entry point (check_rep) on 0.4.x
-    if hasattr(jax, "shard_map"):
-        smap = partial(jax.shard_map, check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        smap = partial(_shard_map, check_rep=False)
-    return smap(
-        fn, mesh=mesh,
+    return jax.shard_map(
+        fn, mesh=mesh, check_vma=False,
         in_specs=(cache_spec, cache_spec, new_spec, new_spec, new_spec,
                   P(), P()),
         out_specs=(cache_spec, cache_spec, new_spec),

@@ -31,35 +31,40 @@ def _is_pspec(x):
     return isinstance(x, PSpec)
 
 
+@partial(jax.jit, static_argnums=(1, 2))
+def _init_leaf(k, spec, dtype):
+    """One parameter leaf, drawn in float32 and cast inside one program,
+    so the float32 draw is a fused temporary rather than a second whole
+    copy of the leaf next to the weights already placed."""
+    if spec.init == "zeros":
+        return jnp.zeros(spec.shape, dtype)
+    if spec.init == "ones":
+        return jnp.ones(spec.shape, dtype)
+    if spec.init == "lambda_init":
+        # RG-LRU Lambda: a in [0.9, 0.999] -> softplus-inverse param
+        u = jax.random.uniform(k, spec.shape, jnp.float32, 0.9, 0.999)
+        return jnp.log(jnp.expm1(-jnp.log(u) / 8.0)).astype(dtype)  # c = 8
+    if spec.init == "dt_bias":
+        u = jax.random.uniform(k, spec.shape, jnp.float32, 1e-3, 1e-1)
+        return jnp.log(jnp.expm1(u)).astype(dtype)
+    if spec.init == "a_log":
+        n = int(np.prod(spec.shape)) if spec.shape else 1
+        return jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)
+                       ).reshape(spec.shape).astype(dtype)
+    scale = spec.scale
+    if scale is None:
+        fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
+        scale = fan_in ** -0.5
+    return (jax.random.normal(k, spec.shape, jnp.float32) * scale).astype(dtype)
+
+
 def init_params(table, key, dtype):
+    """Real parameters for ``table``, placed where ``key`` lives."""
     leaves, treedef = jax.tree.flatten(table, is_leaf=_is_pspec)
     keys = jax.random.split(key, len(leaves))
-    out = []
-    for k, spec in zip(keys, leaves):
-        if spec.init == "zeros":
-            w = jnp.zeros(spec.shape, dtype)
-        elif spec.init == "ones":
-            w = jnp.ones(spec.shape, dtype)
-        elif spec.init == "lambda_init":
-            # RG-LRU Lambda: a in [0.9, 0.999] -> softplus-inverse param
-            u = jax.random.uniform(k, spec.shape, jnp.float32, 0.9, 0.999)
-            lam = jnp.log(jnp.expm1(-jnp.log(u) / 8.0))  # c = 8
-            w = lam.astype(dtype)
-        elif spec.init == "dt_bias":
-            u = jax.random.uniform(k, spec.shape, jnp.float32, 1e-3, 1e-1)
-            w = jnp.log(jnp.expm1(u)).astype(dtype)
-        elif spec.init == "a_log":
-            n = int(np.prod(spec.shape)) if spec.shape else 1
-            w = jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)
-                        ).reshape(spec.shape).astype(dtype)
-        else:
-            scale = spec.scale
-            if scale is None:
-                fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
-                scale = fan_in ** -0.5
-            w = (jax.random.normal(k, spec.shape, jnp.float32) * scale).astype(dtype)
-        out.append(w)
-    return jax.tree.unflatten(treedef, out)
+    dtype = jnp.dtype(dtype)
+    return jax.tree.unflatten(
+        treedef, [_init_leaf(k, spec, dtype) for k, spec in zip(keys, leaves)])
 
 
 def abstract_params(table, dtype):

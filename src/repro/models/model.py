@@ -212,7 +212,8 @@ class Model:
 
     # ------------------------------------------------------------ forward
     def _apply_layer(self, p, bt, x, positions, mode, cache, window,
-                     triangular=True, block_table=None, dst_page=None):
+                     triangular=True, block_table=None, dst_page=None,
+                     layer=None):
         kw = {}
         if bt in ("attn", "mla"):
             kw["triangular"] = triangular
@@ -222,6 +223,8 @@ class Model:
                 kw["block_table"] = block_table
             if dst_page is not None:
                 kw["dst_page"] = dst_page
+            if layer is not None:
+                kw["layer"] = layer
         c_in = cache["mixer"] if cache is not None else None
         x, new_c = BLOCK_APPLY[bt](self.cfg, p["mixer"], x, positions,
                                    mode=mode, cache=c_in, **kw)
@@ -249,7 +252,12 @@ class Model:
         (scalar accepted for B == 1), ``block_table`` (B, N) covering
         every page each sequence occupies through its run, ``dst_page``
         (B, C) page ids the runs' K/V is scattered onto (the scratch page
-        for prefix-shared chunks and padding). Attention-only patterns."""
+        for prefix-shared chunks and padding). Attention-only patterns.
+
+        Paged modes (a ``block_table`` is given) carry the stacked page
+        pool through the layer scan and write each layer's pages in place:
+        slicing it per layer as scan input and restacking it as output
+        would hold the whole pool twice."""
         cfg = self.cfg
         emb = params["embed"]
         if embeddings is not None and tokens is not None:
@@ -289,26 +297,35 @@ class Model:
 
         if self.seg.n_groups:
             pat = self.pattern
+            cb = cache.get("blocks") if cache is not None else None
+            paged = block_table is not None and cb is not None
 
             def group_body(carry, xs):
-                x, aux = carry
-                pslice, cslice = xs
+                x, aux, pool = carry
+                pslice, cslice, li = xs
                 ncs = {}
                 for s, bt in enumerate(pat):
-                    c = cslice[f"slot{s}"] if cslice is not None else None
+                    c = pool if paged else cslice
+                    c = c[f"slot{s}"] if c is not None else None
                     x, nc, a = self._apply_layer(
                         pslice[f"slot{s}"], bt, x, positions, mode, c, window,
-                        triangular, block_table, dst_page)
+                        triangular, block_table, dst_page,
+                        layer=li if paged else None)
                     if nc is not None:
                         ncs[f"slot{s}"] = nc
                     aux = aux + a
-                return (x, aux), (ncs if ncs else None)
+                if paged:
+                    return (x, aux, ncs), None
+                return (x, aux, None), (ncs if ncs else None)
 
             body = jax.checkpoint(group_body) if remat else group_body
-            cb = cache.get("blocks") if cache is not None else None
-            (x, aux_total), ys = jax.lax.scan(
-                body, (x, aux_total), (params["blocks"], cb))
-            if ys is not None:
+            (x, aux_total, pool), ys = jax.lax.scan(
+                body, (x, aux_total, cb if paged else None),
+                (params["blocks"], None if paged else cb,
+                 jnp.arange(self.seg.n_groups, dtype=jnp.int32)))
+            if paged:
+                new_cache["blocks"] = pool
+            elif ys is not None:
                 new_cache["blocks"] = ys
 
         for j, i in enumerate(self.seg.tail):

@@ -149,15 +149,8 @@ def moe_apply(cfg, p, x):
         x_spec = P(batch_axes if batch_axes else None, None)
         fn = partial(_moe_local, k=k, E=E, E_loc=E_loc, C=C,
                      model_axis="model", batch_axes=batch_axes)
-        # jax.shard_map(check_vma=...) only exists on newer jax; fall
-        # back to the experimental entry point (check_rep) on 0.4.x
-        if hasattr(jax, "shard_map"):
-            smap = partial(jax.shard_map, check_vma=False)
-        else:
-            from jax.experimental.shard_map import shard_map as _shard_map
-            smap = partial(_shard_map, check_rep=False)
-        y, aux = smap(
-            fn, mesh=mesh,
+        y, aux = jax.shard_map(
+            fn, mesh=mesh, check_vma=False,
             in_specs=(P(None, None), P("model", None, None),
                       P("model", None, None), P("model", None, None),
                       x_spec),

@@ -140,17 +140,7 @@ def ssm_apply(cfg, p, x, positions, *, mode, cache=None):
                              p["dt_bias"].astype(jnp.float32))
         xh = xs_c.reshape(Bsz, S, H, Pd)
         xh = shard(xh, "batch", None, "ssm_heads", None)
-        from repro import kernels as _k
-        Q = min(cfg.ssm_chunk, S)
-        if _k.enabled() and S % Q == 0:
-            from repro.kernels import ops as _kops
-            y = _kops.ssd(xh.astype(jnp.float32), dt, a, B_c, C_c, chunk=Q)
-            # state for prefill cache still needs the scan path
-            h_fin = None
-            if cache is not None:
-                _, h_fin = ssd_chunked(xh, dt, a, B_c, C_c, cfg.ssm_chunk)
-        else:
-            y, h_fin = ssd_chunked(xh, dt, a, B_c, C_c, cfg.ssm_chunk)
+        y, h_fin = ssd_chunked(xh, dt, a, B_c, C_c, cfg.ssm_chunk)
         y = y + p["D"].astype(jnp.float32)[None, None, :, None] * xh
         new_cache = None
         if cache is not None:

@@ -132,6 +132,9 @@ class _BatcherBase:
         self.model = get_model(cfg)
         self.params = (params if params is not None
                        else self.model.init(jax.random.PRNGKey(seed), dtype))
+        # the batcher's cache and sampling state live (committed) on the
+        # device that holds its weights, so every dispatch runs there
+        self.device = next(iter(jax.tree.leaves(self.params)[0].devices()))
         self.num_slots = num_slots
         self.max_len = max_len
         self.temperature = temperature
@@ -499,11 +502,13 @@ class _BatcherBase:
 
     def _finish_slot(self, si):
         s = self.slots[si]
-        self.finished[s.request_id] = self.tok.decode(
-            list(s.carried) + list(s.generated))
-        self._note_terminal(s.request_id, "completed",
-                            tokens=len(s.carried) + len(s.generated),
+        toks = list(s.carried) + list(s.generated)
+        self.finished[s.request_id] = self.tok.decode(toks)
+        self._note_terminal(s.request_id, "completed", tokens=len(toks),
                             tier=s.tier)
+        rec = self.request_log.get(s.request_id)
+        if rec is not None:
+            rec["tokens"] = toks           # the output stream, as ids
         self.slots[si] = SlotState()
 
     def _profile_sync_target(self):
@@ -532,7 +537,8 @@ class ContinuousBatcher(_BatcherBase):
         # stacked slot caches: leading axis = slot
         one = self.model.init_cache(1, max_len, dtype=jnp.bfloat16)
         self._cache = jax.tree.map(
-            lambda x: jnp.zeros((num_slots,) + x.shape, x.dtype), one)
+            lambda x: jnp.zeros((num_slots,) + x.shape, x.dtype,
+                                device=self.device), one)
         self._decode_all = jax.jit(
             jax.vmap(make_serve_step(self.model), in_axes=(None, 0, 0, 0)),
             donate_argnums=(1,))
@@ -732,7 +738,8 @@ class PagedContinuousBatcher(_BatcherBase):
             # worst case: every slot holds a full private sequence
             num_pages = num_slots * self.pages_per_seq + 1
         self.pool = PagePool(self.model, max_len, page_size, num_pages,
-                             dtype=jnp.bfloat16, sharing=sharing)
+                             dtype=jnp.bfloat16, sharing=sharing,
+                             device=self.device)
         self.block_tables = np.zeros((num_slots, self.pages_per_seq),
                                      np.int32)
         self._decode_all = jax.jit(make_paged_serve_step(self.model),
@@ -775,8 +782,10 @@ class PagedContinuousBatcher(_BatcherBase):
                                       donate_argnums=(1,))
         self._fused_decode = jax.jit(make_fused_decode_step(self.model),
                                      donate_argnums=(1,))
-        self._dev_last = jnp.zeros((num_slots,), jnp.int32)
-        self._dev_gen = jnp.zeros((num_slots, max_len), jnp.int32)
+        self._dev_last = jnp.zeros((num_slots,), jnp.int32,
+                                   device=self.device)
+        self._dev_gen = jnp.zeros((num_slots, max_len), jnp.int32,
+                                  device=self.device)
         # compiled pow2 bucket sizes, persisted across ticks per dimension
         # (rows / chunk pages / block-table widths): re-dispatching into an
         # already-compiled larger bucket beats compiling a tighter one

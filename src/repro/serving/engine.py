@@ -1258,7 +1258,8 @@ class TickOrchestrator:
                         latency_ms=latency,
                         cost=d.island.cost_per_request,
                         sensitivity=d.sensitivity, sanitized=d.sanitize,
-                        decision=d, island_privacy=d.island.privacy)
+                        decision=d, island_privacy=d.island.privacy,
+                        tokens=rec.get("tokens") if rec else None)
         self.log.append(resp)
         self.results[p.rid] = resp
         self._otrace("complete", rid=p.rid, island=d.island.island_id,
@@ -1321,23 +1322,34 @@ def build_island_batchers(cfg, registry, cache="auto", params=None,
     can hold every slot fully private (never stalls), < 1.0 deliberately
     oversubscribes so the pool only fits the workload when prefix sharing
     pays, surfacing eviction pressure to the router. Model parameters are
-    initialized once and shared across islands (same weights everywhere,
-    as with the per-request engine's LocalModelServer).
+    initialized once (float32 unless ``params`` are given) and shared by
+    every island on a device, as with the per-request engine's
+    LocalModelServer.
+
+    SHORE island ``i`` (registry order) lives on ``jax.devices()[i % n]``:
+    its weights copy and page pool are committed there, so on a four-chip
+    host four islands sit on four chips, and on one chip nothing moves.
     """
     from repro.serving.batcher import make_batcher, paged_supported
     if cache == "auto":                 # resolve once so sizing matches
         cache = "paged" if paged_supported(cfg) else "stacked"
+    if params is None:
+        params = get_model(cfg).init(jax.random.PRNGKey(seed), "float32")
     pages_per_seq = -(-max_len // page_size)
+    devices = jax.devices()
+    on_device = {}                      # device -> that device's weights
     out = {}
-    for isl in registry.all():
-        if isl.endpoint != "shore":
-            continue
+    shore = [isl for isl in registry.all() if isl.endpoint == "shore"]
+    for i, isl in enumerate(shore):
+        dev = devices[i % len(devices)]
+        if dev not in on_device:
+            on_device[dev] = jax.device_put(params, dev)
         slots = max(1, int(round(slots_per_capacity_unit
                                  * isl.capacity_units)))
         # page kwargs are computed unconditionally; make_batcher drops
         # them for the stacked manager
-        b = make_batcher(
-            cfg, cache=cache, params=params, num_slots=slots,
+        out[isl.island_id] = make_batcher(
+            cfg, cache=cache, params=on_device[dev], num_slots=slots,
             max_len=max_len, seed=seed, temperature=temperature,
             page_size=page_size, prefill=prefill,
             prefill_token_budget=prefill_token_budget, fused=fused,
@@ -1345,7 +1357,4 @@ def build_island_batchers(cfg, registry, cache="auto", params=None,
             class_aware=class_aware,
             num_pages=max(2, int(slots * pages_per_seq
                                  * pool_headroom)) + 1)
-        if params is None:
-            params = b.params        # share weights across islands
-        out[isl.island_id] = b
     return out

@@ -187,11 +187,13 @@ class PagePool:
     ``model=None`` builds an accounting-only pool (no device arrays) —
     that's what the allocation/free/sharing property tests exercise; the
     serving path passes the real model so ``pages`` holds per-layer
-    (num_pages, page_size, Hkv, D) storage.
+    (num_pages, page_size, Hkv, D) storage, committed to ``device`` (the
+    device holding the owning batcher's weights; None = the default).
     """
 
     def __init__(self, model=None, max_len: int = 256, page_size: int = 16,
-                 num_pages: int = 64, dtype=jnp.bfloat16, sharing: bool = True):
+                 num_pages: int = 64, dtype=jnp.bfloat16, sharing: bool = True,
+                 device=None):
         assert num_pages >= 2, "need at least scratch + 1 usable page"
         if max_len % page_size:
             # the prompt-chunk scatter slices the (1, max_len) dense prefill
@@ -230,14 +232,14 @@ class PagePool:
         self._set_page_fn = None
         if model is not None:
             spec = model.cache_spec(1, max_len)
-            self.pages = self._build_pages(spec, dtype)
+            self.pages = self._build_pages(spec, dtype, device)
             self._write_pages_fn = jax.jit(
                 partial(_write_pages, ps=page_size), donate_argnums=(0,))
             self._copy_page_fn = jax.jit(_copy_page, donate_argnums=(0,))
             self._set_page_fn = jax.jit(_set_page, donate_argnums=(0,))
             self._set_pages_fn = jax.jit(_set_pages, donate_argnums=(0,))
 
-    def _build_pages(self, spec, dtype):
+    def _build_pages(self, spec, dtype, device):
         def _is_sa(v):
             return (isinstance(v, tuple) and len(v) == 2
                     and isinstance(v[0], tuple))
@@ -255,7 +257,7 @@ class PagePool:
             else:                            # (G, 1, S, Hkv, D)
                 g, _, _, hkv, d = shape
                 out = (g, self.num_pages, self.page_size, hkv, d)
-            return jnp.zeros(out, dtype)
+            return jnp.zeros(out, dtype, device=device)
 
         def walk(node):
             out = {}

@@ -13,20 +13,6 @@ if str(SRC) not in sys.path:
 import pytest  # noqa: E402
 
 
-def pytest_collection_modifyitems(config, items):
-    """``tpu``-marked tests need native Mosaic lowering; on any other
-    backend they auto-skip (CI additionally deselects them outright with
-    ``-m "not tpu"`` so they don't clutter the report)."""
-    import jax
-    if jax.default_backend() == "tpu":
-        return
-    skip = pytest.mark.skip(reason="requires a TPU backend "
-                            "(native Pallas compile)")
-    for item in items:
-        if "tpu" in item.keywords:
-            item.add_marker(skip)
-
-
 @pytest.fixture(scope="session")
 def rng_key():
     import jax

@@ -189,40 +189,38 @@ def test_resource_sharing_balances_to_big_battery():
 
 # ---------------------------------------------------- pallas model fast path
 
-def test_pallas_fast_path_matches_xla():
-    """REPRO_PALLAS model path (flash/decode/SSD kernels) must equal the
-    portable XLA path."""
-    import dataclasses
+def test_pallas_fast_path_matches_xla(monkeypatch):
+    """The model path a TPU takes (flash prefill, chunked-prefill and
+    paged-decode kernels, here in interpret mode) must equal the portable
+    XLA path the CPU takes."""
     from repro import kernels
     from repro.configs.base import get_config
     from repro.models.model import get_model
-    try:
-        for arch in ("smollm-135m", "mamba2-370m"):
-            cfg = get_config(arch).reduced()
-            if cfg.ssm_state:
-                cfg = dataclasses.replace(cfg, ssm_chunk=32)
-            m = get_model(cfg)
-            params = m.init(jax.random.PRNGKey(0), "float32")
-            toks = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0,
-                                      cfg.vocab_size)
-            kernels.enable(False)
-            l1, _, _ = m.forward(params, tokens=toks)
-            kernels.enable(True)
-            l2, _, _ = m.forward(params, tokens=toks)
-            np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
-                                       atol=5e-5)
-            # decode path through the kernel too (cache len % 128 == 0)
-            kernels.enable(True)
-            cache = m.init_cache(2, 128, dtype=jnp.float32)
-            _, cache, _ = m.forward(params, mode="full", cache=cache,
-                                    tokens=toks[:, :64])
-            ld, _, _ = m.forward(params, mode="decode",
-                                 tokens=toks[:, 64:65], cache=cache,
-                                 pos=jnp.int32(64))
-            assert bool(jnp.all(jnp.isfinite(ld)))
-    finally:
-        kernels.enable(False)
-        kernels._FORCED = None
+    from repro.serving.kvpool import PagePool
+    cfg = get_config("smollm-135m").reduced()
+    m = get_model(cfg)
+    params = m.init(jax.random.PRNGKey(0), "float32")
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0,
+                              cfg.vocab_size)
+    ps, n_pages = 16, 8
+    pool = PagePool(m, 128, ps, 2 * n_pages + 1, dtype=jnp.float32)
+    bt = jnp.arange(1, 2 * n_pages + 1, dtype=jnp.int32).reshape(2, n_pages)
+
+    def run(on):
+        monkeypatch.setattr(kernels, "enabled", lambda: on)
+        full, _, _ = m.forward(params, tokens=toks)
+        chunk, cache, _ = m.forward(
+            params, mode="chunk", tokens=toks[:, :64], cache=pool.pages,
+            pos=jnp.zeros((2,), jnp.int32), block_table=bt[:, :4],
+            dst_page=bt[:, :4])
+        dec, _, _ = m.forward(params, mode="decode", tokens=toks[:, 64:65],
+                              cache=cache, pos=jnp.full((2,), 64, jnp.int32),
+                              block_table=bt)
+        return full, chunk, dec
+
+    for xla, pallas in zip(run(False), run(True)):
+        np.testing.assert_allclose(np.asarray(xla), np.asarray(pallas),
+                                   atol=5e-5)
 
 
 # --------------------------------------------------- conversation sessions

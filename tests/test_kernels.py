@@ -55,16 +55,20 @@ def test_decode_attention_sweep(B, H, Hkv, S, D, valid, dtype):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,Hkv,P,ps,N,valids", [
-    (2, 8, 2, 24, 16, 8, (1, 128)),          # near-empty + full
-    (1, 4, 1, 9, 8, 4, (17,)),               # mid-page ragged fill
-    (3, 6, 6, 12, 16, 3, (5, 31, 48)),       # per-sequence ragged levels
-    (2, 16, 4, 40, 32, 6, (100, 192)),       # larger pages
+@pytest.mark.parametrize("B,H,Hkv,D,P,ps,N,valids", [
+    (2, 8, 2, 64, 24, 16, 8, (1, 128)),      # near-empty + full
+    (1, 4, 1, 64, 9, 8, 4, (17,)),           # mid-page ragged fill
+    (3, 6, 6, 64, 12, 16, 3, (5, 31, 48)),   # per-sequence ragged levels
+    (2, 16, 4, 64, 40, 32, 6, (100, 192)),   # larger pages
+    (2, 32, 8, 128, 12, 16, 5, (7, 80)),     # qwen3-4b heads: G 4, D 128
+    (2, 9, 3, 64, 12, 16, 5, (33, 64)),      # smollm-135m heads: G 3, D 64
 ])
-def test_paged_decode_attention_sweep(B, H, Hkv, P, ps, N, valids, dtype):
-    """Paged kernel vs oracle across ragged fill levels and page sizes;
-    f32 must match to <= 1e-4 max abs error."""
-    q = jax.random.normal(KEY, (B, H, D := 64), dtype)
+def test_paged_decode_attention_sweep(B, H, Hkv, D, P, ps, N, valids,
+                                      dtype):
+    """Paged kernel vs oracle across ragged fill levels, page sizes and
+    the served models' head layouts; f32 must match to <= 1e-4 max abs
+    error."""
+    q = jax.random.normal(KEY, (B, H, D), dtype)
     kp = jax.random.normal(jax.random.PRNGKey(1), (P, ps, Hkv, D), dtype)
     vp = jax.random.normal(jax.random.PRNGKey(2), (P, ps, Hkv, D), dtype)
     bt = jax.random.randint(jax.random.PRNGKey(3), (B, N), 0, P)
@@ -110,17 +114,20 @@ def test_paged_scratch_pages_fully_masked():
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,Hkv,P,ps,N,T,bq,starts", [
-    (1, 4, 2, 8, 16, 4, 16, 16, (16,)),       # one-page chunk mid-sequence
-    (2, 8, 2, 12, 16, 6, 32, 16, (0, 48)),    # 2-page chunk, q-tile loop
-    (1, 4, 4, 6, 8, 6, 16, 8, (24,)),         # small pages, MHA
-    (2, 16, 4, 16, 16, 8, 64, 32, (32, 0)),   # long chunk, ragged starts
+@pytest.mark.parametrize("B,H,Hkv,D,P,ps,N,T,bq,starts", [
+    (1, 4, 2, 32, 8, 16, 4, 16, 16, (16,)),      # one-page chunk mid-seq
+    (2, 8, 2, 32, 12, 16, 6, 32, 16, (0, 48)),   # 2-page chunk, q-tile loop
+    (1, 4, 4, 32, 6, 8, 6, 16, 8, (24,)),        # small pages, MHA
+    (2, 16, 4, 32, 16, 16, 8, 64, 32, (32, 0)),  # long chunk, ragged starts
+    (2, 32, 8, 128, 10, 16, 4, 32, 16, (32, 0)),  # qwen3-4b heads: G 4
+    (2, 9, 3, 64, 10, 16, 4, 32, None, (16, 32)),  # smollm-135m: G 3
 ])
-def test_chunked_prefill_attention_sweep(B, H, Hkv, P, ps, N, T, bq,
+def test_chunked_prefill_attention_sweep(B, H, Hkv, D, P, ps, N, T, bq,
                                          starts, dtype):
-    """Chunked-prefill kernel vs oracle across chunk lengths, q tiles and
-    per-sequence start offsets; f32 must match to <= 1e-4 max abs error."""
-    q = jax.random.normal(KEY, (B, T, H, D := 32), dtype)
+    """Chunked-prefill kernel vs oracle across chunk lengths, q tiles,
+    per-sequence start offsets and the served models' head layouts; f32
+    must match to <= 1e-4 max abs error."""
+    q = jax.random.normal(KEY, (B, T, H, D), dtype)
     kp = jax.random.normal(jax.random.PRNGKey(1), (P, ps, Hkv, D), dtype)
     vp = jax.random.normal(jax.random.PRNGKey(2), (P, ps, Hkv, D), dtype)
     bt = jax.random.randint(jax.random.PRNGKey(3), (B, N), 0, P)
@@ -174,45 +181,6 @@ def test_chunked_prefill_future_pages_fully_masked():
     vp2 = vp.at[0].set(-1e4)
     o2 = chunked_prefill_attention(q, kp2, vp2, bt, start)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=0)
-
-
-@pytest.mark.tpu
-def test_chunked_prefill_attention_compiles_native_tpu():
-    """Native (non-interpret) Mosaic lowering of the chunked-prefill
-    kernel — deselected on CPU CI via ``-m "not tpu"``."""
-    B, H, Hkv, D, P, ps, N, T = 2, 8, 2, 128, 16, 16, 4, 32
-    q = jax.random.normal(KEY, (B, T, H, D), jnp.bfloat16)
-    kp = jax.random.normal(jax.random.PRNGKey(1), (P, ps, Hkv, D),
-                           jnp.bfloat16)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (P, ps, Hkv, D),
-                           jnp.bfloat16)
-    bt = jax.random.randint(jax.random.PRNGKey(3), (B, N), 0, P)
-    sp = jnp.array([16, 0], jnp.int32)
-    o = chunked_prefill_attention(q, kp, vp, bt, sp, block_q=16,
-                                  interpret=False)
-    o_ref = ref.chunked_prefill_attention(q, kp, vp, bt, sp, D ** -0.5)
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(o_ref, np.float32), **_tol(
-                                   jnp.bfloat16))
-
-
-@pytest.mark.tpu
-def test_paged_decode_attention_compiles_native_tpu():
-    """Native (non-interpret) Mosaic lowering of the paged kernel —
-    deselected on CPU CI via ``-m "not tpu"``."""
-    B, H, Hkv, D, P, ps, N = 2, 8, 2, 128, 16, 16, 4
-    q = jax.random.normal(KEY, (B, H, D), jnp.bfloat16)
-    kp = jax.random.normal(jax.random.PRNGKey(1), (P, ps, Hkv, D),
-                           jnp.bfloat16)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (P, ps, Hkv, D),
-                           jnp.bfloat16)
-    bt = jax.random.randint(jax.random.PRNGKey(3), (B, N), 0, P)
-    valid = jnp.array([13, 60], jnp.int32)
-    o = paged_decode_attention(q, kp, vp, bt, valid, interpret=False)
-    o_ref = ref.paged_decode_attention(q, kp, vp, bt, valid, D ** -0.5)
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(o_ref, np.float32), **_tol(
-                                   jnp.bfloat16))
 
 
 @pytest.mark.parametrize("B,S,H,P,N,Q", [
