@@ -284,10 +284,11 @@ def attn_apply(cfg, p, x, positions, *, mode, cache=None, window=None,
         C = S // ps
         dst = dst_page if dst_page.ndim == 2 else dst_page[None]  # (B,C)
         at = _at_layer(layer, dst.reshape(-1))
-        new_kp = cache["k_pages"].at[at].set(
-            kd.reshape(B * C, ps, *kd.shape[2:]))
-        new_vp = cache["v_pages"].at[at].set(
-            vd.reshape(B * C, ps, *vd.shape[2:]))
+        with jax.named_scope("page_write"):
+            new_kp = cache["k_pages"].at[at].set(
+                kd.reshape(B * C, ps, *kd.shape[2:]))
+            new_vp = cache["v_pages"].at[at].set(
+                vd.reshape(B * C, ps, *vd.shape[2:]))
         o = attend_chunk_paged(q, new_kp, new_vp, block_table, pos[:, 0],
                                scale, layer)
         new_cache = {"k_pages": new_kp, "v_pages": new_vp}
@@ -303,8 +304,9 @@ def attn_apply(cfg, p, x, positions, *, mode, cache=None, window=None,
         # slots carry all-zero block tables, landing on the scratch page
         pi = block_table[jnp.arange(B), posb // ps]
         at = _at_layer(layer, pi, posb % ps)
-        new_kp = cache["k_pages"].at[at].set(kd[:, 0])
-        new_vp = cache["v_pages"].at[at].set(vd[:, 0])
+        with jax.named_scope("page_write"):
+            new_kp = cache["k_pages"].at[at].set(kd[:, 0])
+            new_vp = cache["v_pages"].at[at].set(vd[:, 0])
         o = attend_decode_paged(q, new_kp, new_vp, block_table, posb + 1,
                                 scale, layer)
         new_cache = {"k_pages": new_kp, "v_pages": new_vp}

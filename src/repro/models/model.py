@@ -226,14 +226,18 @@ class Model:
             if layer is not None:
                 kw["layer"] = layer
         c_in = cache["mixer"] if cache is not None else None
-        x, new_c = BLOCK_APPLY[bt](self.cfg, p["mixer"], x, positions,
-                                   mode=mode, cache=c_in, **kw)
+        # device-trace scopes: the mixer by its block type, the
+        # feed-forward as "mlp" (see "Observability", docs/architecture.md)
+        with jax.named_scope(bt):
+            x, new_c = BLOCK_APPLY[bt](self.cfg, p["mixer"], x, positions,
+                                       mode=mode, cache=c_in, **kw)
         aux = jnp.zeros((), jnp.float32)
-        if "moe" in p:
-            y, aux = moe.moe_apply(self.cfg, p["moe"], x)
-            x = x + y
-        elif "mlp" in p:
-            x = x + swiglu_apply(p["mlp"], rms_norm(x, p["mlp"]["ln"]))
+        with jax.named_scope("mlp"):
+            if "moe" in p:
+                y, aux = moe.moe_apply(self.cfg, p["moe"], x)
+                x = x + y
+            elif "mlp" in p:
+                x = x + swiglu_apply(p["mlp"], rms_norm(x, p["mlp"]["ln"]))
         return x, ({"mixer": new_c} if new_c is not None else None), aux
 
     def forward(self, params, *, tokens=None, embeddings=None, mode="full",
@@ -260,14 +264,15 @@ class Model:
         would hold the whole pool twice."""
         cfg = self.cfg
         emb = params["embed"]
-        if embeddings is not None and tokens is not None:
-            x = jnp.concatenate(
-                [embeddings.astype(emb.dtype), emb[tokens]], axis=1)
-        elif embeddings is not None:
-            x = embeddings.astype(emb.dtype)
-        else:
-            x = emb[tokens]
-        x = shard(x, "batch", None, None)
+        with jax.named_scope("embed"):
+            if embeddings is not None and tokens is not None:
+                x = jnp.concatenate(
+                    [embeddings.astype(emb.dtype), emb[tokens]], axis=1)
+            elif embeddings is not None:
+                x = embeddings.astype(emb.dtype)
+            else:
+                x = emb[tokens]
+            x = shard(x, "batch", None, None)
         B, S = x.shape[0], x.shape[1]
 
         if mode == "full":
@@ -331,12 +336,13 @@ class Model:
         for j, i in enumerate(self.seg.tail):
             x, aux_total = run_single(f"tail_{j}", i, x, aux_total)
 
-        x = rms_norm(x, params["final_norm"])
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("bsd,vd->bsv", x, emb)
-        else:
-            logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-        logits = shard(logits, "batch", None, "vocab")
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["final_norm"])
+            if cfg.tie_embeddings:
+                logits = jnp.einsum("bsd,vd->bsv", x, emb)
+            else:
+                logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+            logits = shard(logits, "batch", None, "vocab")
         return logits, (new_cache if new_cache else None), aux_total
 
 

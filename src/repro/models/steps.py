@@ -125,10 +125,12 @@ def make_fused_prefill_step(model, window=None):
         logits, new_cache, _ = model.forward(
             params, mode="chunk", tokens=token, cache=cache, pos=start,
             window=window, block_table=block_table, dst_page=dst_page)
-        rows = jnp.arange(logits.shape[0])
-        bound = jnp.argmax(logits[rows, emit_off], axis=-1).astype(jnp.int32)
-        new_last = last_tok.at[emit_slot].set(bound, mode="drop")
-        new_gen = gen_buf.at[emit_slot, gen_idx].set(bound, mode="drop")
+        with jax.named_scope("emit"):
+            rows = jnp.arange(logits.shape[0])
+            bound = jnp.argmax(logits[rows, emit_off],
+                               axis=-1).astype(jnp.int32)
+            new_last = last_tok.at[emit_slot].set(bound, mode="drop")
+            new_gen = gen_buf.at[emit_slot, gen_idx].set(bound, mode="drop")
         return new_last, new_gen, new_cache
 
     return fused_prefill_step
@@ -161,14 +163,16 @@ def make_fused_decode_step(model, window=None):
     callers ignore the logits, so nothing forces a device sync."""
     def fused_decode_step(params, cache, last_tok, host_mask, host_tok,
                           pos, block_table, write_slot, gen_idx, gen_buf):
-        tok = jnp.where(host_mask, host_tok, last_tok)[:, None]
+        with jax.named_scope("embed"):
+            tok = jnp.where(host_mask, host_tok, last_tok)[:, None]
         logits, new_cache, _ = model.forward(
             params, mode="decode", tokens=tok, cache=cache, pos=pos,
             window=window, block_table=block_table)
-        logits = logits[:, 0, :]
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        new_last = last_tok.at[write_slot].set(nxt, mode="drop")
-        new_gen = gen_buf.at[write_slot, gen_idx].set(nxt, mode="drop")
+        with jax.named_scope("emit"):
+            logits = logits[:, 0, :]
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            new_last = last_tok.at[write_slot].set(nxt, mode="drop")
+            new_gen = gen_buf.at[write_slot, gen_idx].set(nxt, mode="drop")
         return logits, new_last, new_gen, new_cache
 
     return fused_decode_step

@@ -4,7 +4,10 @@ Layout: each island is a **process** (pid), with thread 0 as its queue
 / lifecycle track and one **thread per slot** for residency spans; the
 orchestrator is pid 0 with routing/terminal events on thread 0.
 Migrations render as flow arrows (``s``/``f`` pairs) from the source
-island's lifecycle track to the destination's.
+island's lifecycle track to the destination's. Named host spans
+(``span`` events: ``orch.*`` on the orchestrator, ``batcher.*`` on their
+island) are complete (``X``) events on a "host spans" thread of their
+process.
 
 Timestamps are the wall-ns stamps converted to µs relative to the first
 event — the one place wall clock is the right axis, since Perfetto is a
@@ -20,6 +23,8 @@ a tenant.
 from __future__ import annotations
 
 import json
+
+SPAN_TID = 1 << 20        # the "host spans" thread, clear of slot tids
 
 
 def _us(e, t0):
@@ -56,6 +61,9 @@ def chrome_trace_events(tracer) -> list:
             continue
         meta(pid, f"island:{iid}")
         meta(pid, None, tid=0, tname="lifecycle")
+    for pid in sorted({pids.get(e.island, 0) for e in evs
+                       if e.kind == "span"}):
+        meta(pid, None, tid=SPAN_TID, tname="host spans")
 
     slot_tids: dict = {}          # (pid, slot) -> tid
 
@@ -77,7 +85,12 @@ def chrome_trace_events(tracer) -> list:
     for e in evs:
         pid = pids.get(e.island, 0)
         ts = _us(e, t0)
-        if e.kind in ("queue", "thaw_queue"):
+        if e.kind == "span":
+            out.append({"ph": "X", "pid": pid, "tid": SPAN_TID, "ts": ts,
+                        "dur": e.attrs["dur_ns"] / 1000.0,
+                        "name": e.attrs["name"],
+                        "args": {"tick": e.tick, "work": e.work}})
+        elif e.kind in ("queue", "thaw_queue"):
             out.append({"ph": "B", "pid": pid, "tid": 0, "ts": ts,
                         "name": f"queued r{e.rid}", "args": _args(e)})
             open_q[(pid, e.rid)] = True

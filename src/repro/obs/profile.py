@@ -12,7 +12,9 @@ is finally visible:
   here are recompiles);
 * ``dispatch_submit`` — time spent *inside* the jitted calls as observed
   from the host: XLA argument staging + program launch + (on sync-heavy
-  paths) device compute that the call itself blocks on;
+  paths) device compute that the call itself blocks on. It is charged
+  from the batcher's ``batcher.dispatch`` span sites (``charge``), one
+  launch each, so the profiler and the tracer time the same calls;
 * ``device_sync`` — the explicit ``block_until_ready`` tail the profiler
   issues at tick end so in-flight work is charged to the tick that
   launched it.
@@ -36,6 +38,7 @@ from __future__ import annotations
 import time
 
 from repro.obs.metrics import summarize
+from repro.obs.trace import NO_SPAN
 
 
 class DispatchProfiler:
@@ -44,6 +47,8 @@ class DispatchProfiler:
     tick-scoped and not reentrant."""
 
     PHASES = ("host_plan", "bucket", "dispatch_submit", "device_sync")
+    # span sites (repro.obs.trace) charged to a phase, one launch each
+    SPAN_PHASES = {"batcher.dispatch": "dispatch_submit"}
 
     def __init__(self):
         self.ticks: list[dict] = []      # one record per profiled tick
@@ -89,6 +94,15 @@ class DispatchProfiler:
         tick (no-op outside a tick, so jit wraps need no guards)."""
         return _Phase(self, name)
 
+    def charge(self, span_name: str, inner=NO_SPAN):
+        """The context of span site ``span_name``: ``inner`` (the tracer's
+        span, if any), charged to the phase the site maps to as one
+        launch; ``inner`` alone where it maps to none."""
+        phase = self.SPAN_PHASES.get(span_name)
+        if phase is None:
+            return inner
+        return _Phase(self, phase, inner, dispatches=1)
+
     def add_ns(self, name: str, ns: int, dispatches: int = 0):
         if self._cur is not None:
             self._cur[name] += ns
@@ -119,15 +133,18 @@ class DispatchProfiler:
 
 
 class _Phase:
-    __slots__ = ("prof", "name", "t0")
+    __slots__ = ("prof", "name", "inner", "dispatches", "t0")
 
-    def __init__(self, prof, name):
+    def __init__(self, prof, name, inner=NO_SPAN, dispatches=0):
         self.prof, self.name = prof, name
+        self.inner, self.dispatches = inner, dispatches
 
     def __enter__(self):
+        self.inner.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        self.prof.add_ns(self.name, time.perf_counter_ns() - self.t0)
-        return False
+        self.prof.add_ns(self.name, time.perf_counter_ns() - self.t0,
+                         self.dispatches)
+        return self.inner.__exit__(*exc)

@@ -21,7 +21,20 @@ Event kinds, by scope:
   (per-island TIDE capacity snapshot), ``route`` (chosen island +
   score), ``dispatch`` / ``dispatch_sim``, ``migrate_out`` /
   ``migrate_in`` / ``migrate_return``, ``failover``, ``restart``,
-  ``complete``, ``reject``, ``expire``.
+  ``complete``, ``reject``, ``expire``, ``clock_anchor``;
+* either scope: ``span`` — one named stretch of host work (``name``,
+  ``dur_ns``; the event's ``wall_ns`` is its start), from the fixed
+  vocabulary ``SPAN_NAMES``. Each span is also a
+  ``jax.profiler.TraceAnnotation``, so a profiler trace shows it on the
+  host plane next to the device ops. Without a tracer a span site is one
+  attribute check and the shared no-op context ``NO_SPAN``: no
+  annotation is built.
+
+**One clock with the profiler.** ``wall_ns`` is ``perf_counter_ns``; the
+profiler stamps host events on its own clock. ``clock_anchor()``, called
+once a profiler trace has started, journals a stamp and writes a
+zero-length ``obs.clock_anchor`` annotation right after it;
+``trace_offset`` then maps any journal stamp onto the trace's clock.
 
 **Trust boundary.** The raw event stream is operator-view only — the
 same boundary as the Lighthouse's ``viewer_tier=None`` telemetry: it
@@ -46,6 +59,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 
 @dataclass
 class TraceEvent:
@@ -62,6 +77,65 @@ class TraceEvent:
 # orchestrator-scope kinds that resolve a request exactly once
 TERMINAL_KINDS = ("complete", "reject", "expire")
 
+# the span vocabulary, where each stretch of host work happens:
+SPAN_NAMES = (
+    "orch.tick",            # TickOrchestrator.tick, whole
+    "orch.route",           # the routing pass over the pending pool
+    "orch.route.mist",      # MIST sensitivity analysis of the pool
+    "orch.route.kernel",    # pack, route_batch_tick, host read of its
+                            # outputs (the host waits on the device here)
+    "orch.dispatch",        # routed requests into island batchers
+    "orch.feedback",        # TIDE/LIGHTHOUSE reports, counter roll-up
+    "orch.complete",        # desanitize and build the Response
+    "batcher.tick",         # one island batcher tick, whole
+    "batcher.plan",         # admission, chunk planning, page tables
+    "batcher.dispatch",     # launching one jitted step
+    "batcher.materialize",  # device-to-host read of a finished stream
+)
+CLOCK_ANCHOR = "obs.clock_anchor"
+
+
+class _NoSpan:
+    """The shared context of every span site when no tracer is attached."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+NO_SPAN = _NoSpan()
+
+
+class _Span:
+    __slots__ = ("tracer", "name", "island", "stamp", "ann", "ev")
+
+    def __init__(self, tracer, name, island, stamp):
+        self.tracer, self.name = tracer, name
+        self.island, self.stamp = island, stamp
+
+    def __enter__(self):
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
+        self.ev = self.tracer.emit("span", island=self.island,
+                                   name=self.name, dur_ns=0, **self.stamp)
+        return self
+
+    def __exit__(self, *exc):
+        self.ev.attrs["dur_ns"] = time.perf_counter_ns() - self.ev.wall_ns
+        return self.ann.__exit__(*exc)
+
+
+def trace_offset(anchor: dict, anchor_start_ns: float) -> float:
+    """Nanoseconds to add to a journal stamp to place it on a profiler
+    trace's clock: the trace's ``obs.clock_anchor`` host event starts
+    ``anchor_start_ns``, right after the journal stamp ``anchor`` holds
+    (``Tracer.clock_anchor``). The mapping holds whatever the trace
+    counts from (``ProfileData`` counts from the profile's start)."""
+    return anchor_start_ns - anchor["wall_ns"]
+
 
 class Tracer:
     """Append-only event journal shared by one serving stack (an
@@ -73,6 +147,29 @@ class Tracer:
 
     def __len__(self):
         return len(self.events)
+
+    def span(self, name: str, island: Optional[str] = None, *,
+             tick: int = 0, work: int = 0):
+        """Context manager for one named stretch of host work: a
+        ``TraceAnnotation`` of ``name``, and a ``span`` event appended at
+        entry (so the journal stays in start order) whose ``dur_ns`` is
+        set at exit."""
+        return _Span(self, name, island, {"tick": tick, "work": work})
+
+    def clock_anchor(self) -> dict:
+        """Pair the journal's clock with a running profiler trace's: a
+        ``clock_anchor`` event whose ``wall_ns`` is read just before a
+        zero-length ``obs.clock_anchor`` annotation opens, carrying the
+        offset of the realtime clock (``offset_ns``, realtime less
+        ``perf_counter_ns``). Returns the event's stamps (see
+        ``trace_offset``)."""
+        offset = time.time_ns() - time.perf_counter_ns()
+        ann = TraceAnnotation(CLOCK_ANCHOR)
+        wall = time.perf_counter_ns()
+        with ann:
+            pass
+        self.emit("clock_anchor", wall_ns=wall, offset_ns=offset)
+        return {"wall_ns": wall, "offset_ns": offset}
 
     def emit(self, kind: str, *, island: Optional[str] = None,
              rid: Optional[int] = None, tick: int = 0, work: int = 0,
@@ -164,7 +261,8 @@ class Tracer:
         through the mesh ``TelemetryPolicy`` (round-up quantum +
         value-keyed noise). No islands, no rids, no clocks, no work —
         cumulative work deltas re-expose per-request timing even when
-        aggregated, so they never cross this boundary."""
+        aggregated, so they never cross this boundary. ``span`` and
+        ``clock_anchor`` events (host timing) are never counted."""
         from repro.core.lighthouse import harden_value
 
         def visible(e):

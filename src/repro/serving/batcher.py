@@ -78,6 +78,7 @@ from repro.models.steps import (make_chunked_prefill_step,
                                 make_fused_prefill_step,
                                 make_paged_serve_step, make_prefill_step,
                                 make_serve_step)
+from repro.obs.trace import NO_SPAN
 from repro.serving.kvpool import (SCRATCH_PAGE, PagePool, export_request,
                                   import_request, prefix_chunk_hashes,
                                   resolve_chunk_page)
@@ -162,12 +163,12 @@ class _BatcherBase:
         self._prefill = jax.jit(make_prefill_step(self.model))
         # "admissions" counts requests entering a slot; "prefill_dispatches"
         # counts model prefill dispatches (1/admission monolithic, 1/chunk
-        # chunked). "prefills" is the legacy alias of prefill_dispatches.
+        # chunked).
         # "device_dispatches" counts jitted MODEL program launches (the
         # fused tick collapses many prefill_dispatches into one);
         # "tick_dispatches_max" is the per-tick peak — the deterministic
         # proxy the benchmark gates on
-        self.stats = {"ticks": 0, "prefills": 0, "admissions": 0,
+        self.stats = {"ticks": 0, "admissions": 0,
                       "prefill_dispatches": 0, "decode_tokens": 0,
                       "decode_steps": 0, "queued_peak": 0,
                       "device_dispatches": 0, "tick_dispatches_max": 0}
@@ -182,9 +183,10 @@ class _BatcherBase:
         # rid -> lifecycle record (submit/admit/first-token ticks & work)
         self.request_log: dict[int, dict] = {}
         # observability (opt-in, zero-interference): a Tracer receives
-        # span events stamped on this batcher's tick/work clocks, a
-        # DispatchProfiler times tick phases. Both default off; neither
-        # may touch scheduling state (see src/repro/obs/).
+        # lifecycle events and named spans stamped on this batcher's
+        # tick/work clocks, a DispatchProfiler times tick phases from the
+        # same span sites. Both default off; neither may touch scheduling
+        # state (see src/repro/obs/).
         self.tracer = None
         self.island = ""
         self.profiler = None
@@ -207,6 +209,18 @@ class _BatcherBase:
             self.tracer.emit(kind, island=self.island, rid=rid,
                              tick=self.stats["ticks"],
                              work=self.work_clock, **attrs)
+
+    def _span(self, name):
+        """A ``batcher.*`` span site (``repro.obs.trace``): the tracer's
+        span on this batcher's clocks, charged to an attached profiler's
+        phase where it maps to one; the shared no-op with neither."""
+        tr, prof = self.tracer, self.profiler
+        if tr is None and prof is None:
+            return NO_SPAN
+        sp = NO_SPAN if tr is None else tr.span(
+            name, self.island, tick=self.stats["ticks"],
+            work=self.work_clock)
+        return sp if prof is None else prof.charge(name, sp)
 
     # --------------------------------------------------------- submission
     def submit(self, prompt: str, max_new_tokens=16,
@@ -391,7 +405,6 @@ class _BatcherBase:
 
     def _note_prefill_dispatch(self, tokens, tier=None, rid=None,
                                slot=None):
-        self.stats["prefills"] += 1
         self.stats["prefill_dispatches"] += 1
         self.work_clock += tokens
         self.tier_work[tier] = self.tier_work.get(tier, 0) + tokens
@@ -453,12 +466,14 @@ class _BatcherBase:
         d0 = self.stats["device_dispatches"]
         prof = self.profiler
         if prof is None:
-            self._tick_inner()
+            with self._span("batcher.tick"):
+                self._tick_inner()
         else:
             shapes = getattr(self, "dispatch_shapes", None)
             k0 = len(shapes) if shapes is not None else 0
             prof.tick_begin()
-            self._tick_inner()
+            with self._span("batcher.tick"):
+                self._tick_inner()
             prof.tick_end(self._profile_sync_target())
             if shapes is not None:
                 prof.note_shapes(shapes[k0:])
@@ -569,12 +584,7 @@ class ContinuousBatcher(_BatcherBase):
             toks = jnp.asarray(np.asarray(ids, np.int32)[None])
             cache = self.model.init_cache(1, self.max_len,
                                           dtype=jnp.bfloat16)
-            if self.profiler is not None:
-                with self.profiler.phase("dispatch_submit"):
-                    logits, cache = self._prefill(self.params, cache,
-                                                  {"tokens": toks})
-                self.profiler.add_ns("dispatch_submit", 0, dispatches=1)
-            else:
+            with self._span("batcher.dispatch"):
                 logits, cache = self._prefill(self.params, cache,
                                               {"tokens": toks})
             self.stats["device_dispatches"] += 1
@@ -658,13 +668,7 @@ class ContinuousBatcher(_BatcherBase):
             s = self.slots[si]
             toks[si, 0, 0] = s.generated[-1]
             poss[si] = s.pos
-        if self.profiler is not None:
-            with self.profiler.phase("dispatch_submit"):
-                logits, self._cache = self._decode_all(
-                    self.params, self._cache, jnp.asarray(toks),
-                    jnp.asarray(poss))
-            self.profiler.add_ns("dispatch_submit", 0, dispatches=1)
-        else:
+        with self._span("batcher.dispatch"):
             logits, self._cache = self._decode_all(
                 self.params, self._cache, jnp.asarray(toks),
                 jnp.asarray(poss))
@@ -856,7 +860,8 @@ class PagedContinuousBatcher(_BatcherBase):
         if not s.gen_dev:
             return
         lo = len(s.generated)
-        vals = np.asarray(self._dev_gen[si, lo:lo + s.gen_dev])
+        with self._span("batcher.materialize"):
+            vals = np.asarray(self._dev_gen[si, lo:lo + s.gen_dev])
         s.generated.extend(int(v) for v in vals)
         s.gen_dev = 0
 
@@ -945,12 +950,7 @@ class PagedContinuousBatcher(_BatcherBase):
             toks = jnp.asarray(np.asarray(ids, np.int32)[None])
             cache = self.model.init_cache(1, self.max_len,
                                           dtype=jnp.bfloat16)
-            if self.profiler is not None:
-                with self.profiler.phase("dispatch_submit"):
-                    logits, dense = self._prefill(self.params, cache,
-                                                  {"tokens": toks})
-                self.profiler.add_ns("dispatch_submit", 0, dispatches=1)
-            else:
+            with self._span("batcher.dispatch"):
                 logits, dense = self._prefill(self.params, cache,
                                               {"tokens": toks})
             self.stats["device_dispatches"] += 1
@@ -1072,7 +1072,8 @@ class PagedContinuousBatcher(_BatcherBase):
         slots. A class that exhausts its share stops; nobody inherits
         leftover budget (non-work-conserving on purpose: a tier's
         prefill pace must not depend on whether other tiers had work).
-        All planned rows still fuse into ONE device dispatch."""
+        All planned rows still fuse into ONE device dispatch (returned
+        as in ``_prefill_tick``)."""
         caps = self.tier_quotas
         total = self.prefill_token_budget
         shares = {t: total * c // self.num_slots for t, c in caps.items()}
@@ -1105,8 +1106,7 @@ class PagedContinuousBatcher(_BatcherBase):
                     progress = True
                 if self.constant_shape:
                     break        # one pass max (see _prefill_tick)
-        if rows:
-            self._execute_prefill_rows(rows)
+        return rows
 
     def _admit_ids(self, si, rid, ids, max_new, tier, prompt,
                    carried=(), pending=()):
@@ -1269,7 +1269,8 @@ class PagedContinuousBatcher(_BatcherBase):
         a short prompt sitting behind a long one. ``class_aware`` batchers
         serve higher SLO ranks first (stable sort: rotation order holds
         within a rank), so an interactive prompt's chunks never queue
-        behind a batch prompt's under a tight budget."""
+        behind a batch prompt's under a tight budget. Returns the rows
+        the fused path planned, for ONE dispatch (``_pack_prefill_rows``)."""
         budget = self.prefill_token_budget
         n = self.num_slots
         start = self._prefill_rr
@@ -1301,8 +1302,7 @@ class PagedContinuousBatcher(_BatcherBase):
                 # num_slots (leftover budget rolls to the next tick's
                 # pass — throughput cost, never correctness)
                 break
-        if rows:
-            self._execute_prefill_rows(rows)
+        return rows
 
     def _plan_group(self, si, budget):
         """Resolve plan entries for slot ``si`` into ONE dispatch-worth of
@@ -1415,12 +1415,13 @@ class PagedContinuousBatcher(_BatcherBase):
                 self._note_first_token(s.request_id)
         return row, gtok
 
-    def _execute_prefill_rows(self, rows):
-        """Fused prefill step, execute half: ONE device dispatch for
-        every chunk run planned this tick, across requests. Rows pad to
-        bucketed shapes (row count / run pages / table width); padding
-        rows write only the scratch page and emit nothing, and masked
-        attention keeps real rows away from their garbage."""
+    def _pack_prefill_rows(self, rows):
+        """Fused prefill step, execute half (host side): the inputs of ONE
+        device dispatch for every chunk run planned this tick, across
+        requests. Rows pad to bucketed shapes (row count / run pages /
+        table width); padding rows write only the scratch page and emit
+        nothing, and masked attention keeps real rows away from their
+        garbage."""
         ps = self.page_size
         r_n = self._bucket("rows", len(rows),
                            self.num_slots if self.constant_shape else 1 << 16)
@@ -1448,25 +1449,8 @@ class PagedContinuousBatcher(_BatcherBase):
             emit_slot[r] = row["emit_slot"]
             emit_off[r] = row["emit_off"]
             gen_idx[r] = row["gen_idx"]
-        if self.profiler is not None:
-            with self.profiler.phase("dispatch_submit"):
-                self._dev_last, self._dev_gen, self.pool.pages = \
-                    self._fused_prefill(
-                        self.params, self.pool.pages, jnp.asarray(toks),
-                        jnp.asarray(starts), jnp.asarray(bt),
-                        jnp.asarray(dst), jnp.asarray(emit_slot),
-                        jnp.asarray(emit_off), jnp.asarray(gen_idx),
-                        self._dev_last, self._dev_gen)
-            self.profiler.add_ns("dispatch_submit", 0, dispatches=1)
-        else:
-            self._dev_last, self._dev_gen, self.pool.pages = \
-                self._fused_prefill(
-                    self.params, self.pool.pages, jnp.asarray(toks),
-                    jnp.asarray(starts), jnp.asarray(bt),
-                    jnp.asarray(dst), jnp.asarray(emit_slot),
-                    jnp.asarray(emit_off), jnp.asarray(gen_idx),
-                    self._dev_last, self._dev_gen)
-        self.stats["device_dispatches"] += 1
+        return tuple(jnp.asarray(a) for a in (toks, starts, bt, dst,
+                                              emit_slot, emit_off, gen_idx))
 
     def _dispatch_chunks(self, si, group):
         """ONE model dispatch for a run of consecutive chunks: gathers
@@ -1497,15 +1481,7 @@ class PagedContinuousBatcher(_BatcherBase):
             toks[0, n * ps:n * ps + fill] = s.prompt_ids[j * ps:j * ps + fill]
             dst[n] = d
             fills += fill
-        if self.profiler is not None:
-            with self.profiler.phase("dispatch_submit"):
-                logits, self.pool.pages = self._chunk_prefill(
-                    self.params, self.pool.pages, jnp.asarray(toks),
-                    jnp.int32(start),
-                    jnp.asarray(self.block_tables[si:si + 1, :w]),
-                    jnp.asarray(dst))
-            self.profiler.add_ns("dispatch_submit", 0, dispatches=1)
-        else:
+        with self._span("batcher.dispatch"):
             logits, self.pool.pages = self._chunk_prefill(
                 self.params, self.pool.pages, jnp.asarray(toks),
                 jnp.int32(start),
@@ -1661,17 +1637,35 @@ class PagedContinuousBatcher(_BatcherBase):
         spend the prefill token budget on queued chunks, then ONE fused
         paged decode step for every slot whose prompt is fully prefilled."""
         self.blocked_last_tick = 0
-        self._admit()
-        self.stats["ticks"] += 1
-        if self.prefill_mode == "chunked":
-            if self.tier_quotas:
-                self._prefill_tick_quota()
-            else:
-                self._prefill_tick()
+        with self._span("batcher.plan"):
+            self._admit()
+            self.stats["ticks"] += 1
+            rows = []
+            if self.prefill_mode == "chunked":
+                rows = (self._prefill_tick_quota() if self.tier_quotas
+                        else self._prefill_tick())
+            args = self._pack_prefill_rows(rows) if rows else None
+        if args is not None:
+            with self._span("batcher.dispatch"):
+                self._dev_last, self._dev_gen, self.pool.pages = \
+                    self._fused_prefill(self.params, self.pool.pages, *args,
+                                        self._dev_last, self._dev_gen)
+            self.stats["device_dispatches"] += 1
+        with self._span("batcher.plan"):
+            ready = self._decode_ready()
+        if not ready:
+            return
+        if self.fused:
+            self._decode_fused(ready)
+        else:
+            self._decode_unfused(ready)
+
+    def _decode_ready(self):
+        """The slots that decode this tick: every slot whose prompt is
+        fully prefilled, with a private page for its next token (page
+        alloc and copy-on-write here; preemption when every one stalls)."""
         active = [si for si, s in enumerate(self.slots)
                   if s.active and s.next_chunk >= len(s.chunks)]
-        if not active:
-            return
         ready, stalled = [], []
         for si in active:
             if self._prepare_write_page(si):
@@ -1739,11 +1733,11 @@ class PagedContinuousBatcher(_BatcherBase):
                 if self._prepare_write_page(si):
                     ready.append(si)
                     stalled.remove(si)
-        if not ready:
-            return
-        if self.fused:
-            self._decode_fused(ready)
-            return
+        return ready
+
+    def _decode_unfused(self, ready):
+        """Unfused paged decode of the ready slots: one dispatch, its
+        logits sampled on the host."""
         toks = np.zeros((self.num_slots, 1), np.int32)
         poss = np.zeros((self.num_slots,), np.int32)
         bt = np.zeros_like(self.block_tables)
@@ -1760,13 +1754,7 @@ class PagedContinuousBatcher(_BatcherBase):
         n_live = max(self.slots[si].pos // self.page_size + 1
                      for si in ready)
         self.dispatch_shapes.append(("decode", self.num_slots, n_live))
-        if self.profiler is not None:
-            with self.profiler.phase("dispatch_submit"):
-                logits, self.pool.pages = self._decode_all(
-                    self.params, self.pool.pages, jnp.asarray(toks),
-                    jnp.asarray(poss), jnp.asarray(bt[:, :n_live]))
-            self.profiler.add_ns("dispatch_submit", 0, dispatches=1)
-        else:
+        with self._span("batcher.dispatch"):
             logits, self.pool.pages = self._decode_all(
                 self.params, self.pool.pages, jnp.asarray(toks),
                 jnp.asarray(poss), jnp.asarray(bt[:, :n_live]))
@@ -1799,43 +1787,34 @@ class PagedContinuousBatcher(_BatcherBase):
         temperature > 0 materializes the ready slots and samples on the
         host exactly as the unfused path does."""
         greedy = self.temperature <= 0.0
-        if not greedy:
+        with self._span("batcher.plan"):
+            if not greedy:
+                for si in ready:
+                    self._materialize_slot(si)
+            toks = np.zeros((self.num_slots,), np.int32)
+            host_mask = np.zeros((self.num_slots,), bool)
+            poss = np.zeros((self.num_slots,), np.int32)
+            bt = np.zeros_like(self.block_tables)
+            write_slot = np.full(self.num_slots, self.num_slots, np.int32)
+            gen_idx = np.zeros((self.num_slots,), np.int32)
             for si in ready:
-                self._materialize_slot(si)
-        toks = np.zeros((self.num_slots,), np.int32)
-        host_mask = np.zeros((self.num_slots,), bool)
-        poss = np.zeros((self.num_slots,), np.int32)
-        bt = np.zeros_like(self.block_tables)
-        write_slot = np.full(self.num_slots, self.num_slots, np.int32)
-        gen_idx = np.zeros((self.num_slots,), np.int32)
-        for si in ready:
-            s = self.slots[si]
-            if s.gen_dev == 0:
-                host_mask[si] = True
-                toks[si] = s.generated[-1]
-            poss[si] = s.pos
-            bt[si] = self.block_tables[si]
-            if greedy:
-                write_slot[si] = si
-                gen_idx[si] = len(s.generated) + s.gen_dev
-        # idle/stalled rows: host_mask stays False and their stale
-        # _dev_last token decodes against the scratch page (all-zero
-        # table) — same wasted-FLOPs tradeoff as the unfused path
-        w = self._bucket("decode_w",
-                         max(self.slots[si].pos // self.page_size + 1
-                             for si in ready), self.pages_per_seq)
-        self.dispatch_shapes.append(("decode", self.num_slots, w))
-        if self.profiler is not None:
-            with self.profiler.phase("dispatch_submit"):
-                logits, self._dev_last, self._dev_gen, self.pool.pages = \
-                    self._fused_decode(
-                        self.params, self.pool.pages, self._dev_last,
-                        jnp.asarray(host_mask), jnp.asarray(toks),
-                        jnp.asarray(poss), jnp.asarray(bt[:, :w]),
-                        jnp.asarray(write_slot), jnp.asarray(gen_idx),
-                        self._dev_gen)
-            self.profiler.add_ns("dispatch_submit", 0, dispatches=1)
-        else:
+                s = self.slots[si]
+                if s.gen_dev == 0:
+                    host_mask[si] = True
+                    toks[si] = s.generated[-1]
+                poss[si] = s.pos
+                bt[si] = self.block_tables[si]
+                if greedy:
+                    write_slot[si] = si
+                    gen_idx[si] = len(s.generated) + s.gen_dev
+            # idle/stalled rows: host_mask stays False and their stale
+            # _dev_last token decodes against the scratch page (all-zero
+            # table) — same wasted-FLOPs tradeoff as the unfused path
+            w = self._bucket("decode_w",
+                             max(self.slots[si].pos // self.page_size + 1
+                                 for si in ready), self.pages_per_seq)
+            self.dispatch_shapes.append(("decode", self.num_slots, w))
+        with self._span("batcher.dispatch"):
             logits, self._dev_last, self._dev_gen, self.pool.pages = \
                 self._fused_decode(
                     self.params, self.pool.pages, self._dev_last,

@@ -44,6 +44,7 @@ from repro.data.tokenizer import ByteTokenizer
 from repro.models.model import get_model
 from repro.models.steps import make_prefill_step, make_serve_step
 from repro.obs.metrics import jain_index, latency_summary, percentile
+from repro.obs.trace import NO_SPAN
 from repro.serving.degrade import (SLO_WORK_PER_MS, OverloadPolicy,
                                    RejectReason, slo_rank_map)
 from repro.serving.kvpool import trust_tier_for_sensitivity
@@ -275,14 +276,9 @@ class TickOrchestrator:
         self.waves = waves
         self.registry = registry
         self.batchers = batchers or {}
-        # optional span tracer (repro.obs.Tracer): orchestrator events
-        # (submit/route/migrate/complete) carry island=None; every island
-        # batcher is attached under its island id. Pure observation —
-        # nothing here may read it back into a scheduling decision.
-        self.tracer = tracer
+        self.tracer = None
         if tracer is not None:
-            for iid, b in self.batchers.items():
-                b.attach_tracer(tracer, island=iid)
+            self.attach_tracer(tracer)
         self.cloud = CloudSimulator(seed)
         self.decode_ticks_per_tick = decode_ticks_per_tick
         self.tick_interval_s = tick_interval_s
@@ -356,6 +352,25 @@ class TickOrchestrator:
         hook = getattr(registry, "add_teardown_hook", None)
         if hook is not None:
             hook(self._on_island_deregistered)
+
+    def attach_tracer(self, tracer):
+        """Attach a span tracer (``repro.obs.Tracer``): orchestrator
+        events (submit/route/migrate/complete) and ``orch.*`` spans carry
+        island=None; every island batcher is attached under its island
+        id. Pure observation — nothing here may read it back into a
+        scheduling decision."""
+        self.tracer = tracer
+        for iid, b in self.batchers.items():
+            b.attach_tracer(tracer, island=iid)
+
+    def _span(self, name):
+        """An ``orch.*`` span site (``repro.obs.trace``) on the clocks
+        ``_otrace`` stamps; the shared no-op without a tracer."""
+        if self.tracer is None:
+            return NO_SPAN
+        return self.tracer.span(
+            name, tick=self.tick_stats["ticks"],
+            work=sum(b.work_clock for b in self.batchers.values()))
 
     def _otrace(self, kind, rid=None, **attrs):
         """Orchestrator-scope span event: tick = orchestrator tick, work
@@ -894,14 +909,16 @@ class TickOrchestrator:
             return [waves.route(p.req) for p in pool]
         decisions: list = [None] * len(pool)
         live = []                        # (pool index, sensitivity)
-        for idx, p in enumerate(pool):
-            if not waves._limiter.allow(p.req.user, waves.tide.clock):
-                decisions[idx] = Decision(None, False, "rate_limited", -1.0)
-                continue
-            s_r = (p.req.sensitivity_override
-                   if p.req.sensitivity_override is not None
-                   else waves.mist.analyze(p.req.query).score)
-            live.append((idx, s_r))
+        with self._span("orch.route.mist"):
+            for idx, p in enumerate(pool):
+                if not waves._limiter.allow(p.req.user, waves.tide.clock):
+                    decisions[idx] = Decision(None, False, "rate_limited",
+                                              -1.0)
+                    continue
+                s_r = (p.req.sensitivity_override
+                       if p.req.sensitivity_override is not None
+                       else waves.mist.analyze(p.req.query).score)
+                live.append((idx, s_r))
         islands = waves.lighthouse.get_islands()
         # a crashed LIGHTHOUSE serves its cached list unfiltered; drop
         # draining/failed islands here so the batched kernel can never
@@ -933,54 +950,55 @@ class TickOrchestrator:
                                       for i in range(ds_cols - len(ds_ids))]
         else:
             ds_ids_padded = []
-        tbl = rj.pack_islands(islands, ds_ids_padded, waves.tide,
-                              pol.trust_mode)
-        # bucket the pool to the next power of two so online serving (a
-        # different m every tick) compiles O(log m) kernel shapes, not one
-        # per pool size. Padding rows carry sensitivity 2.0: infeasible on
-        # every island (privacy <= 1), never queued (queue_local needs
-        # privacy >= s_r too), so they add no load and touch no hysteresis.
-        m = len(live)
-        M = 1 << (m - 1).bit_length()
-        pad = M - m
-        reqs = rj.pack_requests(
-            [s for _, s in live] + [2.0] * pad,
-            [waves.tide.threshold(pool[idx].req.priority)
-             for idx, _ in live] + [0.0] * pad,
-            [pool[idx].req.deadline_ms for idx, _ in live]
-            + [math.inf] * pad,
-            [ds_ids.index(pool[idx].req.dataset)
-             if pool[idx].req.dataset else -1 for idx, _ in live]
-            + [-1] * pad,
-            [pool[idx].req.priority == "primary" for idx, _ in live]
-            + [False] * pad,
-            n_datasets=max(len(ds_ids), 1))
-        # request×island constraints outside the packed tables
-        extra = np.ones((M, len(islands)), bool)
-        for row, (idx, _) in enumerate(live):
-            r = pool[idx].req
-            for col, isl in enumerate(islands):
-                if r.model and isl.models and r.model not in isl.models:
-                    extra[row, col] = False
-                if pol.allowed_jurisdictions is not None and \
-                        isl.jurisdiction not in pol.allowed_jurisdictions:
-                    extra[row, col] = False
-        state = rj.pack_tide_state(islands, waves.tide)
-        budget = (pol.budget_per_request
-                  if pol.budget_per_request is not None else np.inf)
-        weights = jnp.array([pol.w_cost, pol.w_latency, pol.w_privacy],
-                            jnp.float32)
-        assign, acc, que, score, ncand, new_state = rj.route_batch_tick(
-            tbl, reqs, weights, state, jnp.asarray(extra),
-            mode=pol.mode, on_infeasible=pol.on_infeasible, budget=budget,
-            min_trust=pol.min_trust, cost_scale=pol.cost_scale,
-            latency_scale=pol.latency_scale_ms)
-        rj.unpack_tide_state(new_state, islands, waves.tide)
-        assign = np.asarray(assign)
-        acc = np.asarray(acc)
-        que = np.asarray(que)
-        score = np.asarray(score)
-        ncand = np.asarray(ncand)
+        with self._span("orch.route.kernel"):
+            tbl = rj.pack_islands(islands, ds_ids_padded, waves.tide,
+                                  pol.trust_mode)
+            # bucket the pool to the next power of two so online serving (a
+            # different m every tick) compiles O(log m) kernel shapes, not one
+            # per pool size. Padding rows carry sensitivity 2.0: infeasible on
+            # every island (privacy <= 1), never queued (queue_local needs
+            # privacy >= s_r too), so they add no load and touch no hysteresis.
+            m = len(live)
+            M = 1 << (m - 1).bit_length()
+            pad = M - m
+            reqs = rj.pack_requests(
+                [s for _, s in live] + [2.0] * pad,
+                [waves.tide.threshold(pool[idx].req.priority)
+                 for idx, _ in live] + [0.0] * pad,
+                [pool[idx].req.deadline_ms for idx, _ in live]
+                + [math.inf] * pad,
+                [ds_ids.index(pool[idx].req.dataset)
+                 if pool[idx].req.dataset else -1 for idx, _ in live]
+                + [-1] * pad,
+                [pool[idx].req.priority == "primary" for idx, _ in live]
+                + [False] * pad,
+                n_datasets=max(len(ds_ids), 1))
+            # request×island constraints outside the packed tables
+            extra = np.ones((M, len(islands)), bool)
+            for row, (idx, _) in enumerate(live):
+                r = pool[idx].req
+                for col, isl in enumerate(islands):
+                    if r.model and isl.models and r.model not in isl.models:
+                        extra[row, col] = False
+                    if pol.allowed_jurisdictions is not None and \
+                            isl.jurisdiction not in pol.allowed_jurisdictions:
+                        extra[row, col] = False
+            state = rj.pack_tide_state(islands, waves.tide)
+            budget = (pol.budget_per_request
+                      if pol.budget_per_request is not None else np.inf)
+            weights = jnp.array([pol.w_cost, pol.w_latency, pol.w_privacy],
+                                jnp.float32)
+            assign, acc, que, score, ncand, new_state = rj.route_batch_tick(
+                tbl, reqs, weights, state, jnp.asarray(extra),
+                mode=pol.mode, on_infeasible=pol.on_infeasible, budget=budget,
+                min_trust=pol.min_trust, cost_scale=pol.cost_scale,
+                latency_scale=pol.latency_scale_ms)
+            rj.unpack_tide_state(new_state, islands, waves.tide)
+            assign = np.asarray(assign)
+            acc = np.asarray(acc)
+            que = np.asarray(que)
+            score = np.asarray(score)
+            ncand = np.asarray(ncand)
         for row, (idx, s_r) in enumerate(live):
             if not acc[row]:
                 decisions[idx] = Decision(None, False, "infeasible", s_r)
@@ -1001,6 +1019,10 @@ class TickOrchestrator:
     # --------------------------------------------------------------- tick
     def tick(self) -> list:
         """One scheduling tick; returns the Responses completed in it."""
+        with self._span("orch.tick"):
+            return self._tick()
+
+    def _tick(self) -> list:
         waves = self.waves
         completed: list[Response] = []
         self._advance_mesh_work()
@@ -1013,112 +1035,20 @@ class TickOrchestrator:
         if self.fair_tenancy and len(pool) > 1:
             self._fair_order(pool)
         if pool:
-            if self.tracer is not None:
-                # per-island capacity snapshot for this routing pass —
-                # peek_capacity is the side-effect-free read (capacity()
-                # would advance TIDE's EWMA state and perturb routing)
-                self._otrace("route_tick", pool=len(pool), capacities={
-                    i.island_id: round(
-                        waves.tide.peek_capacity(i.island_id), 4)
-                    for i in self.registry.all()})
-            for p, d in zip(pool, self._route_pool(pool)):
-                if not d.accepted:
-                    # nowhere to migrate: the draining source keeps it
-                    # and finishes it under its original decision
-                    # (draining islands finish what nobody can take — a
-                    # graceful drain never drops in-flight work)
-                    if p.ticket is not None \
-                            and self._return_to_source(p, p.ticket):
-                        continue
-                    self.rejected.append(d)
-                    self.results[p.rid] = None
-                    self._placement_backoff.pop(p.rid, None)
-                    self._class_count(p.req, "rejected")
-                    self._otrace("reject", rid=p.rid, reason=d.reason)
-                    continue
-                self.tick_stats["routed"] += 1
-                self._placement_backoff.pop(p.rid, None)
-                island = d.island
-                self._otrace("route", rid=p.rid,
-                             island=island.island_id,
-                             score=(round(d.score, 4)
-                                    if d.score is not None else None),
-                             reason=d.reason,
-                             n_candidates=d.n_candidates)
-                query = (d.sanitized_history[-1] if d.sanitize
-                         else p.req.query)
-                b = self.batchers.get(island.island_id)
-                tkt, p.ticket = p.ticket, None
-                if tkt is not None and tkt.prompt != query:
-                    # the new island sanitizes differently: nothing
-                    # computed for the old text is reusable (fail closed)
-                    self.tick_stats["restarts"] += 1
-                    self._otrace("restart", rid=p.rid,
-                                 reason="sanitize_mismatch")
-                    tkt = None
-                if b is not None:
-                    if tkt is not None and not self._ticket_fits(b, tkt):
-                        # routed to a batcher too small for the resumed
-                        # context: prefer finishing at the source; failing
-                        # that, restart here from the prompt alone
-                        if self._return_to_source(p, tkt):
-                            continue
-                        self.tick_stats["restarts"] += 1
-                        self._otrace("restart", rid=p.rid,
-                                     reason="ticket_too_large")
-                        tkt = None
-                    if tkt is not None:
-                        if (tkt.pages or tkt.dense is not None) and \
-                                not self._import_allowed(island, tkt.tier):
-                            # destination tier may not receive raw KV
-                            # (page records OR a dense cache row): keep
-                            # the progress, recompute the context
-                            tkt = tkt.without_pages()
-                        brid = b.submit_ticket(tkt)
-                        self._otrace("migrate_in", rid=p.rid,
-                                     island=island.island_id, brid=brid,
-                                     source=tkt.source,
-                                     with_pages=bool(tkt.pages
-                                                     or tkt.dense
-                                                     is not None))
-                        # drain pressure: thawing a context is real work
-                        # for the destination (page copies or a recompute
-                        # prefill — both scale with the context length) —
-                        # charge it so subsequent migrations spread
-                        # instead of dogpiling
-                        waves.tide.add_load(
-                            island.island_id,
-                            len(tkt.context_ids())
-                            / MIGRATION_TOKENS_PER_UNIT)
-                    else:
-                        # KV pages this request produces carry its MIST
-                        # trust tier; prefix sharing is only legal within
-                        # a tier
-                        brid = b.submit(
-                            query, p.max_new_tokens,
-                            trust_tier=trust_tier_for_sensitivity(
-                                d.sensitivity),
-                            slo_rank=self._slo_rank(p.req))
-                        self._otrace("dispatch", rid=p.rid,
-                                     island=island.island_id, brid=brid)
-                    self._local_inflight[(island.island_id, brid)] = (p, d)
-                else:
-                    # simulated executor: a cross-executor move cannot
-                    # preserve a KV stream, so a migrated request restarts
-                    # here (counted — the bit-exact guarantee is SHORE-to-
-                    # SHORE)
-                    if tkt is not None:
-                        self.tick_stats["restarts"] += 1
-                        self._otrace("restart", rid=p.rid,
-                                     reason="cross_executor")
-                    text, exec_ms = self.cloud.complete(island, query)
-                    ready = waves.tide.clock + \
-                        (island.latency_ms + exec_ms) / 1000.0
-                    self._sim_inflight.append((ready, p, d, text, exec_ms))
-                    self._otrace("dispatch_sim", rid=p.rid,
-                                 island=island.island_id,
-                                 exec_ms=round(exec_ms, 3))
+            with self._span("orch.route"):
+                if self.tracer is not None:
+                    # per-island capacity snapshot for this routing pass —
+                    # peek_capacity is the side-effect-free read (capacity()
+                    # would advance TIDE's EWMA state and perturb routing)
+                    self._otrace("route_tick", pool=len(pool), capacities={
+                        i.island_id: round(
+                            waves.tide.peek_capacity(i.island_id), 4)
+                        for i in self.registry.all()})
+                decisions = self._route_pool(pool)
+            with self._span("orch.dispatch"):
+                self._dispatch(pool, decisions)
         # SHORE: continuous-batching decode steps
+        blocked_by = {}
         for iid, b in self.batchers.items():
             blocked = 0            # accumulated: b.tick() resets its count
             for _ in range(self.decode_ticks_per_tick):
@@ -1130,6 +1060,7 @@ class TickOrchestrator:
                 self._util_sum[iid] = self._util_sum.get(iid, 0.0) \
                     + b.utilization()
                 self._util_n[iid] = self._util_n.get(iid, 0) + 1
+            blocked_by[iid] = blocked
             for brid in list(b.finished):
                 key = (iid, brid)
                 if key not in self._local_inflight:
@@ -1145,6 +1076,44 @@ class TickOrchestrator:
                     continue
                 completed.append(self._complete(
                     p, d, text, rec=b.request_log.get(brid)))
+        with self._span("orch.feedback"):
+            self._feedback(blocked_by)
+        # HORIZON / simulated completions whose latency has elapsed
+        still = []
+        for ready, p, d, text, exec_ms in self._sim_inflight:
+            if ready <= waves.tide.clock:
+                # elapsed virtual time already contains the island's base
+                # latency (it set the ready time) — don't add it again
+                completed.append(self._complete(p, d, text, exec_ms,
+                                                include_base=False))
+            else:
+                still.append((ready, p, d, text, exec_ms))
+        self._sim_inflight = still
+        self._finalize_drains()
+        if self.debug_audit:
+            # end-of-tick page-pool invariant check: refcount-vs-table
+            # violations surface at the tick that caused them
+            for iid, b in self.batchers.items():
+                kv_pool = getattr(b, "pool", None)
+                if kv_pool is not None:
+                    try:
+                        kv_pool.audit()
+                    except AssertionError as e:
+                        raise AssertionError(
+                            f"PagePool audit failed on {iid} at tick "
+                            f"{self.tick_stats['ticks']}: {e}") from e
+        self._snapshot_fairness()
+        self.tick_stats["ticks"] += 1
+        return completed
+
+    def _feedback(self, blocked_by):
+        """TIDE and LIGHTHOUSE feedback once the island batchers have
+        ticked: each island's KV-pool pressure and telemetry (with the
+        admissions ``blocked_by`` island), progress, SLO lag, the
+        counter roll-up, and the advance of virtual time."""
+        waves = self.waves
+        for iid, b in self.batchers.items():
+            blocked = blocked_by[iid]
             # KV-pool pressure feedback + telemetry (paged batchers only)
             kv_pool = getattr(b, "pool", None)
             if kv_pool is not None:
@@ -1217,36 +1186,116 @@ class TickOrchestrator:
             # islands still beat — they are alive, just not routable)
             if self.registry.status(isl.island_id) != STATUS_FAILED:
                 waves.lighthouse.heartbeat(isl.island_id)
-        # HORIZON / simulated completions whose latency has elapsed
-        still = []
-        for ready, p, d, text, exec_ms in self._sim_inflight:
-            if ready <= waves.tide.clock:
-                # elapsed virtual time already contains the island's base
-                # latency (it set the ready time) — don't add it again
-                completed.append(self._complete(p, d, text, exec_ms,
-                                                include_base=False))
-            else:
-                still.append((ready, p, d, text, exec_ms))
-        self._sim_inflight = still
-        self._finalize_drains()
-        if self.debug_audit:
-            # end-of-tick page-pool invariant check: refcount-vs-table
-            # violations surface at the tick that caused them
-            for iid, b in self.batchers.items():
-                kv_pool = getattr(b, "pool", None)
-                if kv_pool is not None:
-                    try:
-                        kv_pool.audit()
-                    except AssertionError as e:
-                        raise AssertionError(
-                            f"PagePool audit failed on {iid} at tick "
-                            f"{self.tick_stats['ticks']}: {e}") from e
-        self._snapshot_fairness()
-        self.tick_stats["ticks"] += 1
-        return completed
 
-    def _complete(self, p, d, text, exec_ms=0.0,
-                  include_base=True, rec=None) -> Response:
+    def _dispatch(self, pool, decisions):
+        """Each routed request of the pool into its island: a SHORE
+        island's batcher (a thaw for a migration ticket), else the
+        simulated executor; refusals resolve here."""
+        waves = self.waves
+        for p, d in zip(pool, decisions):
+            if not d.accepted:
+                # nowhere to migrate: the draining source keeps it
+                # and finishes it under its original decision
+                # (draining islands finish what nobody can take — a
+                # graceful drain never drops in-flight work)
+                if p.ticket is not None \
+                        and self._return_to_source(p, p.ticket):
+                    continue
+                self.rejected.append(d)
+                self.results[p.rid] = None
+                self._placement_backoff.pop(p.rid, None)
+                self._class_count(p.req, "rejected")
+                self._otrace("reject", rid=p.rid, reason=d.reason)
+                continue
+            self.tick_stats["routed"] += 1
+            self._placement_backoff.pop(p.rid, None)
+            island = d.island
+            self._otrace("route", rid=p.rid,
+                         island=island.island_id,
+                         score=(round(d.score, 4)
+                                if d.score is not None else None),
+                         reason=d.reason,
+                         n_candidates=d.n_candidates)
+            query = (d.sanitized_history[-1] if d.sanitize
+                     else p.req.query)
+            b = self.batchers.get(island.island_id)
+            tkt, p.ticket = p.ticket, None
+            if tkt is not None and tkt.prompt != query:
+                # the new island sanitizes differently: nothing
+                # computed for the old text is reusable (fail closed)
+                self.tick_stats["restarts"] += 1
+                self._otrace("restart", rid=p.rid,
+                             reason="sanitize_mismatch")
+                tkt = None
+            if b is not None:
+                if tkt is not None and not self._ticket_fits(b, tkt):
+                    # routed to a batcher too small for the resumed
+                    # context: prefer finishing at the source; failing
+                    # that, restart here from the prompt alone
+                    if self._return_to_source(p, tkt):
+                        continue
+                    self.tick_stats["restarts"] += 1
+                    self._otrace("restart", rid=p.rid,
+                                 reason="ticket_too_large")
+                    tkt = None
+                if tkt is not None:
+                    if (tkt.pages or tkt.dense is not None) and \
+                            not self._import_allowed(island, tkt.tier):
+                        # destination tier may not receive raw KV
+                        # (page records OR a dense cache row): keep
+                        # the progress, recompute the context
+                        tkt = tkt.without_pages()
+                    brid = b.submit_ticket(tkt)
+                    self._otrace("migrate_in", rid=p.rid,
+                                 island=island.island_id, brid=brid,
+                                 source=tkt.source,
+                                 with_pages=bool(tkt.pages
+                                                 or tkt.dense
+                                                 is not None))
+                    # drain pressure: thawing a context is real work
+                    # for the destination (page copies or a recompute
+                    # prefill — both scale with the context length) —
+                    # charge it so subsequent migrations spread
+                    # instead of dogpiling
+                    waves.tide.add_load(
+                        island.island_id,
+                        len(tkt.context_ids())
+                        / MIGRATION_TOKENS_PER_UNIT)
+                else:
+                    # KV pages this request produces carry its MIST
+                    # trust tier; prefix sharing is only legal within
+                    # a tier
+                    brid = b.submit(
+                        query, p.max_new_tokens,
+                        trust_tier=trust_tier_for_sensitivity(
+                            d.sensitivity),
+                        slo_rank=self._slo_rank(p.req))
+                    self._otrace("dispatch", rid=p.rid,
+                                 island=island.island_id, brid=brid)
+                self._local_inflight[(island.island_id, brid)] = (p, d)
+            else:
+                # simulated executor: a cross-executor move cannot
+                # preserve a KV stream, so a migrated request restarts
+                # here (counted — the bit-exact guarantee is SHORE-to-
+                # SHORE)
+                if tkt is not None:
+                    self.tick_stats["restarts"] += 1
+                    self._otrace("restart", rid=p.rid,
+                                 reason="cross_executor")
+                text, exec_ms = self.cloud.complete(island, query)
+                ready = waves.tide.clock + \
+                    (island.latency_ms + exec_ms) / 1000.0
+                self._sim_inflight.append((ready, p, d, text, exec_ms))
+                self._otrace("dispatch_sim", rid=p.rid,
+                             island=island.island_id,
+                             exec_ms=round(exec_ms, 3))
+
+    def _complete(self, *args, **kwargs) -> Response:
+        with self._span("orch.complete"):
+            return self._respond(*args, **kwargs)
+
+    def _respond(self, p, d, text, exec_ms=0.0,
+                 include_base=True, rec=None) -> Response:
         self._account_completion(p.req, rec)
         if d.sanitize and d.placeholder_store is not None:
             text = self.waves.mist.desanitize(text, d.placeholder_store)

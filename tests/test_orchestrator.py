@@ -194,7 +194,7 @@ def test_orchestrator_end_to_end_with_batcher():
     assert s["route_calls"] >= 1
     # SHORE work actually went through the continuous batcher
     if any(r.island_id == "laptop" for r in orch.log):
-        assert bat.stats["prefills"] >= 1
+        assert bat.stats["prefill_dispatches"] >= 1
         assert bat.stats["decode_steps"] >= 1
 
 

@@ -45,7 +45,7 @@ def test_continuous_batcher_completes_all():
     assert all(isinstance(v, str) for v in done.values())
     # queue (5 requests) > slots (2): continuous admission must have
     # recycled slots
-    assert b.stats["prefills"] == 5
+    assert b.stats["prefill_dispatches"] == 5
     assert b.stats["queued_peak"] >= 3
     assert b.stats["decode_tokens"] >= 5 * 3
 
