@@ -812,10 +812,16 @@ class PagedContinuousBatcher(_BatcherBase):
         # launch geometry from timing/power even without this log), so it
         # is deliberately public and the adversary harness consumes it.
         self.dispatch_shapes: list = []
+        # decode_kv_pages_live: pages the decoding sequences hold, summed
+        # over decode dispatches; decode_kv_pages_grid: the dispatches'
+        # table capacity (num_slots x table width), which the paged
+        # decode kernel once stepped through in full
         self.stats.update({"share_hits": 0, "cow_copies": 0, "stalls": 0,
                            "preemptions": 0, "rejected_too_large": 0,
                            "prefix_tokens_skipped": 0,
-                           "prefill_chunk_tokens": 0})
+                           "prefill_chunk_tokens": 0,
+                           "decode_kv_pages_live": 0,
+                           "decode_kv_pages_grid": 0})
 
     # -------------------------------------------------------- fused-tick
     def _bucket(self, kind, need, cap) -> int:
@@ -1735,6 +1741,11 @@ class PagedContinuousBatcher(_BatcherBase):
                     stalled.remove(si)
         return ready
 
+    def _note_decode_pages(self, ready, w):
+        self.stats["decode_kv_pages_live"] += sum(
+            self.slots[si].pos // self.page_size + 1 for si in ready)
+        self.stats["decode_kv_pages_grid"] += self.num_slots * w
+
     def _decode_unfused(self, ready):
         """Unfused paged decode of the ready slots: one dispatch, its
         logits sampled on the host."""
@@ -1754,6 +1765,7 @@ class PagedContinuousBatcher(_BatcherBase):
         n_live = max(self.slots[si].pos // self.page_size + 1
                      for si in ready)
         self.dispatch_shapes.append(("decode", self.num_slots, n_live))
+        self._note_decode_pages(ready, n_live)
         with self._span("batcher.dispatch"):
             logits, self.pool.pages = self._decode_all(
                 self.params, self.pool.pages, jnp.asarray(toks),
@@ -1814,6 +1826,7 @@ class PagedContinuousBatcher(_BatcherBase):
                              max(self.slots[si].pos // self.page_size + 1
                                  for si in ready), self.pages_per_seq)
             self.dispatch_shapes.append(("decode", self.num_slots, w))
+            self._note_decode_pages(ready, w)
         with self._span("batcher.dispatch"):
             logits, self._dev_last, self._dev_gen, self.pool.pages = \
                 self._fused_decode(

@@ -139,3 +139,25 @@ def test_fused_stochastic_parity(cfg, params):
     bu, outu = _run(cfg, params, wl, fused=False, temperature=0.9)
     bf, outf = _run(cfg, params, wl, fused=True, temperature=0.9)
     assert outf == outu
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_decode_kv_page_counters_match_hand_count(cfg, params, fused):
+    """``decode_kv_pages_live`` counts the pages each decoding sequence
+    holds in every decode dispatch, ``decode_kv_pages_grid`` the
+    dispatch's table capacity (slots x width). One request of ``n``
+    answer tokens after an ``L``-token prompt decodes ``n - 1`` steps,
+    feeding position ``L + k`` at step ``k``: it holds ``(L + k) // 16
+    + 1`` pages there, and the table is that many pages wide (the fused
+    path rounds the width up to a power of two, at most the 96 // 16
+    pages of a slot)."""
+    n = 24
+    b, _ = _run(cfg, params, [("a short prompt", n, 0)], fused=fused)
+    L = b.request_log[0]["prompt_tokens"]
+    held = [(L + k) // 16 + 1 for k in range(n - 1)]
+    width = [min(1 << (p - 1).bit_length(), 6) if fused else p
+             for p in held]
+    assert b.stats["decode_steps"] == n - 1
+    assert b.stats["decode_kv_pages_live"] == sum(held)
+    assert b.stats["decode_kv_pages_grid"] == 3 * sum(width)
+    assert len(set(held)) > 1          # the answer crosses a page boundary

@@ -62,19 +62,42 @@ def test_decode_attention_sweep(B, H, Hkv, S, D, valid, dtype):
     (2, 16, 4, 64, 40, 32, 6, (100, 192)),   # larger pages
     (2, 32, 8, 128, 12, 16, 5, (7, 80)),     # qwen3-4b heads: G 4, D 128
     (2, 9, 3, 64, 12, 16, 5, (33, 64)),      # smollm-135m heads: G 3, D 64
+    # empty rows beside full ones: the blocked kernel (D a multiple of
+    # 128: qwen3-4b, glm4-9b's two KV heads, paligemma's D 256) and the
+    # per-page kernel (D 64 and 32)
+    (4, 32, 8, 128, 12, 16, 4, (0, 40, 0, 64)),
+    (3, 9, 3, 64, 24, 16, 20, (320, 0, 17)),
+    (3, 4, 2, 32, 24, 16, 20, (0, 300, 17)),
+    (2, 32, 2, 128, 24, 16, 20, (0, 300)),
+    (2, 8, 1, 256, 24, 16, 20, (300, 0)),
+    # 40 pages: two full blocks and a partial third; fills that end
+    # inside a page of a full block, of the partial block, at its end
+    (3, 32, 8, 128, 48, 16, 40, (300, 617, 640)),
+    (2, 9, 3, 64, 48, 16, 40, (617, 255)),
+    # a stacked (L, P) pool read at its last layer
+    (2, 32, 8, 128, (3, 24), 16, 20, (200, 5)),
+    (2, 9, 3, 64, (2, 24), 16, 6, (90, 33)),
+    # the served cell's dispatch: 8 slots x 128 pages, two sequences of
+    # ~1.4k tokens, six idle slots (fill 1)
+    (8, 32, 8, 128, 64, 16, 128, (1400, 1, 1, 1377, 1, 1, 1, 1)),
 ])
 def test_paged_decode_attention_sweep(B, H, Hkv, D, P, ps, N, valids,
                                       dtype):
-    """Paged kernel vs oracle across ragged fill levels, page sizes and
-    the served models' head layouts; f32 must match to <= 1e-4 max abs
-    error."""
+    """Paged kernel vs oracle across ragged fill levels, page sizes, the
+    served models' head layouts, partial blocks and stacked pools (``P``
+    as ``(L, P)``, read at layer ``L - 1``); f32 must match to <= 1e-4
+    max abs error. A row with no filled token reads zeros."""
+    pool = P if isinstance(P, tuple) else (P,)
+    layer = pool[0] - 1 if len(pool) == 2 else None
     q = jax.random.normal(KEY, (B, H, D), dtype)
-    kp = jax.random.normal(jax.random.PRNGKey(1), (P, ps, Hkv, D), dtype)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (P, ps, Hkv, D), dtype)
-    bt = jax.random.randint(jax.random.PRNGKey(3), (B, N), 0, P)
+    kp = jax.random.normal(jax.random.PRNGKey(1), (*pool, ps, Hkv, D), dtype)
+    vp = jax.random.normal(jax.random.PRNGKey(2), (*pool, ps, Hkv, D), dtype)
+    bt = jax.random.randint(jax.random.PRNGKey(3), (B, N), 0, pool[-1])
     valid = jnp.asarray(valids, jnp.int32)
-    o = paged_decode_attention(q, kp, vp, bt, valid)
-    o_ref = ref.paged_decode_attention(q, kp, vp, bt, valid, D ** -0.5)
+    o = paged_decode_attention(q, kp, vp, bt, valid, layer=layer)
+    o_ref = ref.paged_decode_attention(q, kp, vp, bt, valid, D ** -0.5,
+                                       layer=layer)
+    o_ref = jnp.where((valid > 0)[:, None, None], o_ref, 0)
     tol = _tol(dtype) if dtype == jnp.bfloat16 else \
         dict(rtol=1e-5, atol=1e-4)
     np.testing.assert_allclose(np.asarray(o, np.float32),

@@ -23,6 +23,16 @@ from repro.kernels.paged_attention import paged_decode_attention
 WIDTHS = {"qwen3-4b": (32, 8, 128), "smollm-135m": (9, 3, 64),
           "small": (8, 2, 128)}
 PAGE = 16
+# the served cell's decode dispatch: 8 slots x 128 pages read from
+# qwen3-4b's stacked pool of 36 layers x 1025 pages at a given layer
+CELL, CELL_POOL = (8, 128), (36, 1025)
+# the paged decode kernel also serves musicgen-large (32 KV heads of 64)
+# and paligemma-3b (one KV head of 256)
+DECODE_WIDTHS = {**WIDTHS, "musicgen-large": (32, 32, 64),
+                 "paligemma-3b": (8, 1, 256)}
+# the decode kernel reads the pool in place: any temporary it needs is
+# far below one layer's pool (2.6 MB for qwen3-4b's 1025 pages)
+DECODE_TEMP_BYTES = 1 << 20
 
 
 @pytest.fixture(scope="module")
@@ -54,16 +64,24 @@ def _kernel_in(compiled):
     return "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("width", sorted(WIDTHS))
-@pytest.mark.parametrize("B,N", [(4, 128), (2, 4)])
+@pytest.mark.parametrize("width", sorted(DECODE_WIDTHS))
+@pytest.mark.parametrize("B,N", [(4, 128), (2, 4), CELL])
 def test_paged_decode_compiles_for_v5e(one_chip, width, B, N):
-    H, Hkv, D = WIDTHS[width]
-    pool = ((256, PAGE, Hkv, D), jnp.bfloat16)
-    c = _compile(lambda q, k, v, bt, vl: paged_decode_attention(
-        q, k, v, bt, vl, interpret=False), one_chip,
+    """Compiles for a v5e and never copies the pool: a view of the pool
+    that is not a bitcast would show as a temporary of the pool's size.
+    The exception is a stacked pool of heads narrower than the 128 lanes,
+    which XLA's default layout stores with the page index in the lanes,
+    so the pool is laid out again for any Mosaic kernel that reads it."""
+    H, Hkv, D = DECODE_WIDTHS[width]
+    pages = CELL_POOL if (B, N) == CELL else (256,)
+    pool = ((*pages, PAGE, Hkv, D), jnp.bfloat16)
+    c = _compile(lambda q, k, v, bt, vl, ly: paged_decode_attention(
+        q, k, v, bt, vl, layer=ly, interpret=False), one_chip,
         ((B, H, D), jnp.bfloat16), pool, pool,
-        ((B, N), jnp.int32), ((B,), jnp.int32))
+        ((B, N), jnp.int32), ((B,), jnp.int32), ((), jnp.int32))
     assert _kernel_in(c)
+    if D % 128 == 0 or len(pages) == 1:
+        assert c.memory_analysis().temp_size_in_bytes <= DECODE_TEMP_BYTES
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
